@@ -115,6 +115,9 @@ def main(argv=None) -> None:
                     help="derive and write baseline thresholds from this run")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import tables
 
     if args.smoke:

@@ -36,8 +36,9 @@ cfg = UGConfig(ef_spatial=24, ef_attribute=48, max_edges_if=24, max_edges_is=24,
                iterations=2, repair_width=8, exact_spatial=True, block=1024)
 t0 = time.perf_counter()
 # On-device sharded build (DESIGN.md §12): one shard_map program constructs
-# all 4 shard-local UGs in parallel — ring-KNN bootstrap + shard-local
-# attribute orders + the same jitted prune/repair iterations build_ug runs.
+# all 4 shard-local UGs in parallel — exact KNN over each shard's rows +
+# shard-local attribute orders + the same jitted prune/repair iterations
+# build_ug runs.
 sidx = build_sharded_store(mesh, x, ints, cfg, index_axes=("data",))
 jax.block_until_ready(sidx.store.nbrs)
 print(f"built 4 shard-local UGs on-device in {time.perf_counter()-t0:.1f}s "

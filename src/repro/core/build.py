@@ -147,7 +147,8 @@ def refine_candidates(
             x, intervals, pool, cfg, keep, backend
         )
         cand = nbrs  # retained neighbors seed the next round (Alg. 2 line 10)
-        repair = scatter_repairs(w_w, w_v, n, cfg.repair_width)
+        if t + 1 < cfg.iterations:  # the last round's repairs feed nothing
+            repair = scatter_repairs(w_w, w_v, n, cfg.repair_width)
         deg_means.append(jnp.mean(jnp.sum(nbrs >= 0, axis=1).astype(jnp.float32)))
     return nbrs, stat, jnp.stack(deg_means)
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.prune import squared_dist
-from repro.kernels.util import pad_rows, pad_to, segment_scatter
+from repro.kernels.util import pad_rows, pad_to, segment_scatter, sort_key_i32
 
 
 class KnnState(NamedTuple):
@@ -51,37 +51,84 @@ def merge_topk(ids_a, d_a, ids_b, d_b, k: int):
     return out_ids, out_d
 
 
-def _block_knn_scan(x: jnp.ndarray, queries: jnp.ndarray, k: int, block: int = 4096):
-    """Exact top-k of ``queries`` against corpus ``x`` by streaming blocks."""
-    nq = queries.shape[0]
-    ids = jnp.full((nq, k), -1, jnp.int32)
-    d = jnp.full((nq, k), jnp.inf, jnp.float32)
-    n = x.shape[0]
-    for s in range(0, n, block):
-        xb = x[s : s + block]
-        db = squared_dist(queries, xb)
-        bids = jnp.arange(s, s + xb.shape[0], dtype=jnp.int32)
-        bids = jnp.broadcast_to(bids, db.shape)
-        take = min(k, xb.shape[0])
-        neg, idx = jax.lax.top_k(-db, take)
-        ids, d = merge_topk(ids, d, jnp.take_along_axis(bids, idx, axis=-1), -neg, k)
-    return ids, d
+def brute_force_knn(
+    x: jnp.ndarray,
+    k: int,
+    block: int = 2048,
+    x_block: int = 4096,
+    valid: jnp.ndarray | None = None,
+) -> KnnState:
+    """Exact KNN graph (self excluded) as one program: ``lax.map`` over
+    ``block``-row query tiles, each a ``lax.scan`` over ``x_block``-row
+    corpus chunks (matmul distances, chunk top-k, merge into the running
+    top-k).  ``valid`` (``(n,)`` bool) drops rows from the candidates, as
+    the pad rows of a shard.
+
+    The row norms are computed before the scan, as separate operations: a
+    norm fused into the distance program rounds differently on the CPU, and
+    this way the graph equals the one the earlier block-by-block code built,
+    bit for bit."""
+    x32 = x.astype(jnp.float32)
+    norms = jnp.sum(x32 * x32, axis=-1)
+    return _knn_scan(x32, norms, valid, k=k, block=block, x_block=x_block)
 
 
-def brute_force_knn(x: jnp.ndarray, k: int, block: int = 2048) -> KnnState:
-    """Exact KNN graph (self excluded) — small-n oracle and test reference."""
-    n = x.shape[0]
-    ids_all = []
-    d_all = []
-    for s in range(0, n, block):
-        q = x[s : s + block]
-        ids, d = _block_knn_scan(x, q, k + 1)
-        self_ids = jnp.arange(s, s + q.shape[0], dtype=jnp.int32)[:, None]
-        d = jnp.where(ids == self_ids, jnp.inf, d)
-        order = jnp.argsort(d, axis=-1)[:, :k]
-        ids_all.append(jnp.take_along_axis(ids, order, axis=-1))
-        d_all.append(jnp.take_along_axis(d, order, axis=-1))
-    return KnnState(jnp.concatenate(ids_all), jnp.concatenate(d_all))
+@functools.partial(jax.jit, static_argnames=("k", "block", "x_block"))
+def _knn_scan(x, norms, valid, *, k: int, block: int, x_block: int) -> KnnState:
+    """The scan of :func:`brute_force_knn`.  Full tiles and chunks go
+    through ``lax.map``/``lax.scan``; a ragged last tile or chunk keeps its
+    own shape rather than being padded, because the CPU's matmul rounds
+    differently at another width."""
+    n, d = x.shape
+    ok = jnp.ones((n,), bool) if valid is None else valid
+
+    def split(arrays, size):
+        """(full blocks stacked on a new leading axis, ragged tail, starts)"""
+        full = (n // size) * size
+        stacked = tuple(a[:full].reshape((-1, size) + a.shape[1:]) for a in arrays)
+        tail = tuple(a[full:] for a in arrays) if full < n else None
+        return stacked, tail, jnp.arange(0, full, size, dtype=jnp.int32), full
+
+    chunks, last_chunk, c_starts, c_full = split((x, norms, ok), x_block)
+
+    def one_tile(q, qn, q0):
+        qid = q0 + jnp.arange(q.shape[0], dtype=jnp.int32)
+
+        def chunk(state, c):
+            xb, xn, okb, s = c
+            cid = s + jnp.arange(xb.shape[0], dtype=jnp.int32)
+            ip = jnp.einsum("id,jd->ij", q, xb, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)  # see squared_dist
+            db = jnp.maximum(qn[:, None] + xn[None, :] - 2.0 * ip, 0.0)
+            db = jnp.where(okb[None, :] & (cid != qid[:, None]), db, jnp.inf)
+            neg, idx = jax.lax.top_k(-db, min(k, xb.shape[0]))
+            # Chunks hold disjoint ids, so the merge is one top-k over the
+            # running list followed by the chunk's (ties keep that order).
+            ids = jnp.concatenate([state[0], cid[idx]], axis=1)
+            neg, sel = jax.lax.top_k(
+                jnp.concatenate([-state[1], neg], axis=1), k)
+            ids = jnp.take_along_axis(ids, sel, axis=1)
+            return (jnp.where(jnp.isfinite(neg), ids, -1), -neg), None
+
+        state = (jnp.full((q.shape[0], k), -1, jnp.int32),
+                 jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
+        if c_full:
+            state = jax.lax.scan(chunk, state, chunks + (c_starts,))[0]
+        if last_chunk is not None:
+            state = chunk(state, last_chunk + (jnp.int32(c_full),))[0]
+        return state
+
+    tiles, last_tile, t_starts, t_full = split((x, norms), block)
+    ids, dist = [], []
+    if t_full:
+        i, dd = jax.lax.map(lambda t: one_tile(*t), tiles + (t_starts,))
+        ids.append(i.reshape(t_full, k))
+        dist.append(dd.reshape(t_full, k))
+    if last_tile is not None:
+        i, dd = one_tile(*last_tile, jnp.int32(t_full))
+        ids.append(i)
+        dist.append(dd)
+    return KnnState(jnp.concatenate(ids), jnp.concatenate(dist))
 
 
 def _reverse_candidates(ids: jnp.ndarray, r_max: int) -> jnp.ndarray:
@@ -177,6 +224,7 @@ def candidate_pool_width(ef_spatial: int, ef_attribute: int) -> int:
     return ef_spatial + attribute_width(ef_attribute)
 
 
+@functools.partial(jax.jit, static_argnames=("ef_attribute",))
 def attribute_candidates(intervals: jnp.ndarray, ef_attribute: int) -> jnp.ndarray:
     """Alg. 1 lines 3-10: neighbors in the four interval-derived sort orders."""
     n = intervals.shape[0]
@@ -189,7 +237,7 @@ def attribute_candidates(intervals: jnp.ndarray, ef_attribute: int) -> jnp.ndarr
         [jnp.arange(-w, 0, dtype=jnp.int32), jnp.arange(1, w + 1, dtype=jnp.int32)]
     )
     for kv in keys:
-        order = jnp.argsort(kv, stable=True).astype(jnp.int32)       # rank -> id
+        order = jnp.argsort(sort_key_i32(kv), stable=True).astype(jnp.int32)  # rank -> id
         inv = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
         pos = inv[:, None] + offsets[None, :]                         # (n, 2w)
         ok = (pos >= 0) & (pos < n)

@@ -5,7 +5,7 @@ minimum and prefix maximum of right endpoints (with arg-indices) — let a valid
 entry node be found in O(log n) for both IF and IS queries, or NULL certified
 when no valid node exists.
 
-Built with ``jax.lax.associative_scan`` so the structure is jittable and can
+Built from cumulative min/max scans so the structure is jittable and can
 be constructed per shard inside ``shard_map`` (each index shard owns its own
 entry arrays; see DESIGN.md §4).
 """
@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import intervals as iv
+from repro.kernels.util import sort_key_i32
 
 
 class EntryIndex(NamedTuple):
@@ -28,21 +29,55 @@ class EntryIndex(NamedTuple):
     prefmax_r_id: jnp.ndarray   # (n,) int32 — arg node id of that maximum
 
 
+_SCAN_BLOCK = 1024
+
+
+def _cumulative(x: jnp.ndarray, op: str, reverse: bool) -> jnp.ndarray:
+    """``lax.cummin``/``cummax`` of a 1-D array as a two-level scan: along
+    ``_SCAN_BLOCK``-wide rows, then across the rows' totals.  Exact (min and
+    max do not round), and at a million rows a TPU compiles it far faster
+    than the 1-D scan of f32 values (PERF.md has the times)."""
+    n = x.shape[0]
+    big = jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).max
+    fill = jnp.asarray(big if op == "min" else -big, x.dtype)
+    scan = jax.lax.cummin if op == "min" else jax.lax.cummax
+    comb = jnp.minimum if op == "min" else jnp.maximum
+    rows = -(-n // _SCAN_BLOCK)
+    xb = jnp.concatenate([x, jnp.full((rows * _SCAN_BLOCK - n,), fill, x.dtype)])
+    inner = scan(xb.reshape(rows, _SCAN_BLOCK), axis=1, reverse=reverse)
+    total = scan(inner[:, 0] if reverse else inner[:, -1], reverse=reverse)
+    # what the rows scanned before each row carry into it
+    carry = (jnp.concatenate([total[1:], fill[None]]) if reverse
+             else jnp.concatenate([fill[None], total[:-1]]))
+    return comb(inner, carry[:, None]).reshape(-1)[:n]
+
+
 def _argscan(vals: jnp.ndarray, ids: jnp.ndarray, op: str, reverse: bool):
-    """Associative scan carrying (value, arg-id) pairs."""
+    """Running min (``reverse``: over each suffix) or max (over each
+    prefix) of ``vals``, with the id of the element that holds it; on ties
+    the element met first in scan order keeps it.
 
-    def combine(a, b):
-        av, ai = a
-        bv, bi = b
-        if op == "min":
-            take_b = bv < av
-        else:
-            take_b = bv > av
-        return jnp.where(take_b, bv, av), jnp.where(take_b, bi, ai)
+    Built from cumulative min/max only: the value scan, then for each
+    position the nearest scan-order position where a strictly better value
+    starts.  (A pairwise ``associative_scan`` gives the same answer but
+    compiles in minutes at a million rows on a TPU.)"""
+    n = vals.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    best = _cumulative(vals, op, reverse)
+    # best over the positions scanned before this one (sentinel at the start)
+    sentinel = jnp.full((1,), jnp.inf if op == "min" else -jnp.inf, vals.dtype)
+    prev = (jnp.concatenate([best[1:], sentinel]) if reverse
+            else jnp.concatenate([sentinel, best[:-1]]))
+    starts = (vals < prev) if op == "min" else (vals > prev)
+    starts = starts.at[n - 1 if reverse else 0].set(True)
+    if reverse:
+        at = _cumulative(jnp.where(starts, pos, n), "min", True)
+    else:
+        at = _cumulative(jnp.where(starts, pos, -1), "max", False)
+    return best, ids[at]
 
-    return jax.lax.associative_scan(combine, (vals, ids), reverse=reverse)
 
-
+@jax.jit
 def build_entry_index(
     intervals: jnp.ndarray, node_mask: jnp.ndarray | None = None
 ) -> EntryIndex:
@@ -62,7 +97,7 @@ def build_entry_index(
     else:
         r_for_min = r
         r_for_max = r
-    order = jnp.argsort(l, stable=True).astype(jnp.int32)
+    order = jnp.argsort(sort_key_i32(l), stable=True).astype(jnp.int32)
     l_s = l[order]
     rmin_s = r_for_min[order]
     rmax_s = r_for_max[order]

@@ -114,15 +114,18 @@ class UGIndex:
         )
         return cls(store, config, dt)
 
-    def with_dtype(self, dtype: str, *, rerank: bool | None = None) -> "UGIndex":
+    def with_dtype(
+        self, dtype: str, *, rerank: bool | None = None, pq_m: int | None = None
+    ) -> "UGIndex":
         """Re-encode the vector planes (same graph, same ids): the
         cross-dtype parity harness — search quality of a ``bf16``/``int8``
-        plane is measured against the f32 plane *on the identical graph*."""
+        plane is measured against the f32 plane *on the identical graph*.
+        ``pq_m`` is the pq subspace count (default ``default_pq_m``)."""
         if rerank is None:
             rerank = dtype in ("int8", "pq")
         x = self.store.vectors_f32()
         store = self.store.replace(
-            plane=VectorPlane.encode(x, dtype),
+            plane=VectorPlane.encode(x, dtype, pq_m=pq_m),
             rerank=VectorPlane.encode(x, "f32") if rerank else None,
         )
         return self.with_store(store)

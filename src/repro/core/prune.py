@@ -39,12 +39,19 @@ class PruneResult(NamedTuple):
 
 
 def squared_dist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Blocked ‖a−b‖² via the matmul identity; fp32 accumulation on the MXU."""
+    """Blocked ‖a−b‖² via the matmul identity, in full f32 (``HIGHEST``).
+
+    A TPU's default f32 matmul rounds its operands to bf16, and through the
+    identity's cancellation that error is larger than the gap between
+    consecutive neighbours; ``HIGHEST`` keeps these distances exact for
+    every caller (the prune, NN-descent, the sharded ring's KNN).  On the
+    CPU it changes nothing."""
     a32 = a.astype(jnp.float32)
     b32 = b.astype(jnp.float32)
     an = jnp.sum(a32 * a32, axis=-1)
     bn = jnp.sum(b32 * b32, axis=-1)
-    ip = jnp.einsum("...id,...jd->...ij", a32, b32, preferred_element_type=jnp.float32)
+    ip = jnp.einsum("...id,...jd->...ij", a32, b32, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     d = an[..., :, None] + bn[..., None, :] - 2.0 * ip
     return jnp.maximum(d, 0.0)
 

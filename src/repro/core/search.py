@@ -13,7 +13,7 @@ Two generations of the hot loop live here (DESIGN.md §8/§10):
 * ``backend="pallas" | "xla"`` — the fused multi-expansion pipeline: the
   whole batch steps together, each step expands the ``W`` best unexpanded
   frontier nodes per query, scores all ``W·M`` neighbors through
-  ``ops.expand_score`` (scalar-prefetch row gather on TPU — the
+  ``ops.expand_score`` (id-driven tile gather on TPU — the
   ``(B, C, d)`` candidate tensor is never materialized), dedups candidate
   ids with the sort-based ``dedup_first`` (no ``(B, C, C)`` intermediate),
   and folds them into the sorted beam with the bitonic partial-merge kernel
@@ -217,12 +217,16 @@ def _make_fused_step(
     # one loop-invariant LUT instead of rebuilding it per step (None for
     # non-pq planes).
     lut = ops.pq_lut(plane, q32)
+    # Likewise the HBM tile view the Pallas kernels gather from (a padded
+    # copy of the plane when its width is not lane-aligned): once per batch.
+    tiles = ops.plane_tiles(plane.data, backend)
 
     def score(ids_c, valid):
         """Squared distances of the masked candidate ids via the
         expand-score kernel on the store's plane (+inf where invalid)."""
         return ops.expand_score_plane(
-            plane, jnp.where(valid, ids_c, -1), q32, backend=backend, lut=lut
+            plane, jnp.where(valid, ids_c, -1), q32, backend=backend, lut=lut,
+            tiles=tiles,
         )
 
     def predicate(obj_int):
@@ -649,14 +653,17 @@ def search_step_memory_profile(
 @functools.partial(jax.jit, static_argnames=("is_filter", "k"))
 def _brute_force_block(xb, ib, mb, q32, qn, q_int, ids, d, start, *, is_filter, k):
     """One jitted ground-truth block step: matmul-identity distances
-    (``‖x‖²+‖q‖²−2·x·q`` — no ``(nq, block, d)`` diff tensor), predicate
+    (``‖x‖²+‖q‖²−2·x·q`` at full f32 precision — no ``(nq, block, d)``
+    diff tensor), predicate
     mask, exact block top-k, fold into the running top-k.  ``mb`` is the
     block's alive mask (tombstoned/free slots never enter the truth set)."""
     from repro.core.candidates import merge_topk
 
     xb32 = xb.astype(jnp.float32)
     xn = jnp.sum(xb32 * xb32, axis=-1)
-    ip = q32 @ xb32.T
+    # HIGHEST: on a TPU a default-precision f32 matmul runs as bf16 passes,
+    # which would make the recall oracle itself approximate.
+    ip = jnp.dot(q32, xb32.T, precision=jax.lax.Precision.HIGHEST)
     db = jnp.maximum(qn[:, None] + xn[None, :] - 2.0 * ip, 0.0)
     if is_filter:
         ok = iv.contains(q_int[:, None, :], ib[None, :, :])

@@ -21,13 +21,14 @@ Collective schedule (see DESIGN.md §4 and EXPERIMENTS.md §Perf):
   ``k`` per chip: cross-pod bytes drop by the pod size (16×).
 
 Construction (DESIGN.md §12): :func:`build_sharded_store` builds every
-shard's graph **on device** in one jitted ``shard_map`` program — the
-ring-KNN bootstrap (``ppermute`` pipeline, masked to own-shard rows)
-replaces per-shard NN-descent, shard-local attribute sort orders supply
-the Alg. 1 interval candidates, and the same jitted ``_prune_all`` /
-repair iterations the single-host build runs (``build.refine_candidates``)
-refine each shard — no per-shard host ``build_ug`` calls, no round-robin
-numpy padding loop.  :func:`build_sharded_index_host` remains as the
+shard's graph **on device** in one jitted ``shard_map`` program — exact
+KNN over the shard's own rows (the blocked scan of
+``candidates.brute_force_knn``) supplies the spatial candidates,
+shard-local attribute sort orders the Alg. 1 interval candidates, and the
+same jitted ``_prune_all`` / repair iterations the single-host build runs
+(``build.refine_candidates``) refine each shard — no per-shard host
+``build_ug`` calls, no round-robin numpy padding loop, and no row staged
+on one device.  :func:`build_sharded_index_host` remains as the
 serial host reference the parity tests compare against.
 """
 from __future__ import annotations
@@ -42,16 +43,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import intervals as iv
 from repro.core.build import refine_candidates
-from repro.core.candidates import attribute_candidates, merge_topk
+from repro.core.candidates import attribute_candidates, brute_force_knn, merge_topk
 from repro.core.entry import build_entry_index, get_entry_batch_flags, get_entry_flags
 from repro.core.prune import squared_dist
 from repro.core.search import beam_search_flags
 from repro.core.store import (
     IndexStore, VectorPlane, quantization_params, train_pq_codebooks,
 )
-
-from repro import compat
-from repro.compat import shard_map
 
 
 class ShardedIndex(NamedTuple):
@@ -116,17 +114,17 @@ def shard_index(
     pad rows would otherwise widen the per-dim ranges and inflate the
     quantization error), or passed via ``qparams``, and replicated.
     """
-    x = jnp.asarray(x)
+    row = NamedSharding(mesh, P(tuple(index_axes)))
+    x_d = jax.device_put(np.asarray(x), row)   # rows straight to their shards
     if dtype in ("int8", "pq") and qparams is None:
         real = np.asarray(global_ids) >= 0
-        xr = x[jnp.asarray(real)]
         qparams = (
-            quantization_params(xr) if dtype == "int8"
-            else train_pq_codebooks(xr)
+            quantization_params(x_d, mask=jax.device_put(real, row))
+            if dtype == "int8" else train_pq_codebooks(np.asarray(x)[real])
         )
     store = IndexStore(
-        plane=VectorPlane.encode(x, dtype, qparams),
-        rerank=VectorPlane.encode(x, "f32") if rerank else None,
+        plane=VectorPlane.encode(x_d, dtype, qparams),
+        rerank=VectorPlane.encode(x_d, "f32") if rerank else None,
         intervals=jnp.asarray(intervals),
         nbrs=jnp.asarray(nbrs),
         status=jnp.asarray(status),
@@ -136,10 +134,9 @@ def shard_index(
         lambda s: NamedSharding(mesh, s), store_pspecs(store, index_axes),
         is_leaf=lambda v: isinstance(v, P),
     )
-    row = NamedSharding(mesh, P(tuple(index_axes)))
     return ShardedIndex(
         jax.device_put(store, shardings),
-        jax.device_put(jnp.asarray(global_ids), row),
+        jax.device_put(np.asarray(global_ids), row),
     )
 
 
@@ -249,7 +246,7 @@ def make_sharded_search_fn(
             return sharded(sidx.store, sidx.global_ids, q_v, q_int, flags)
 
         in_specs = (ShardedIndex(store_specs, row), rep, rep)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -345,38 +342,30 @@ def make_shard_probe_fns(
 # --------------------------------------------------------------------------
 # Ring-streamed exact KNN (distributed candidate bootstrap)
 # --------------------------------------------------------------------------
-def _ring_knn_step_fn(axis: str, k: int, *, same_shard_of: int | None = None):
-    """Shared body of the ring passes: every step scores the local rows
-    against the visiting column block and folds the result into the running
-    top-k; the block then moves one hop around the ring.
+def make_ring_knn_fn(mesh: Mesh, *, axis: str = "data", k: int = 32):
+    """Exact KNN graph over a row-sharded corpus via a ``ppermute`` ring.
 
-    ``same_shard_of=None`` keeps every candidate (global exact KNN);
-    ``same_shard_of=S`` keeps only candidates of the caller's own shard
-    under the round-robin layout (``gid % S == me``) and returns their
-    *shard-local* ids (``gid // S``) — the bootstrap of the on-device
-    sharded build, where the per-shard graph may only reference own rows.
+    Each step, every shard scores its rows against the visiting column block
+    and folds the result into its running top-k; the block then moves one hop
+    around the ring.  After ``n_shards`` steps every pair has been scored.
+    This is the sharded replacement for NN-descent bootstrap on corpora that
+    exceed a single host (DESIGN.md §4).
     """
 
     def ring(x, gids):
         nloc = x.shape[0]
-        size = compat.axis_size(axis)
-        me = jax.lax.axis_index(axis)
+        size = jax.lax.axis_size(axis)
         perm = [(i, (i + 1) % size) for i in range(size)]
 
         def step(carry, _):
             blk_x, blk_ids, best_i, best_d = carry
             d = squared_dist(x, blk_x)                       # (nloc, blk)
             keep = (blk_ids[None, :] != gids[:, None]) & (blk_ids >= 0)[None, :]
-            if same_shard_of is not None:
-                keep = keep & ((blk_ids % same_shard_of) == me)[None, :]
-                cand_pool = blk_ids // same_shard_of         # shard-local ids
-            else:
-                cand_pool = blk_ids
             d = jnp.where(keep, d, jnp.inf)
             take = min(k, blk_x.shape[0])
             neg, idx = jax.lax.top_k(-d, take)
             cand_ids = jnp.take_along_axis(
-                jnp.broadcast_to(cand_pool[None, :], d.shape), idx, axis=-1
+                jnp.broadcast_to(blk_ids[None, :], d.shape), idx, axis=-1
             )
             cand_ids = jnp.where(jnp.isfinite(neg), cand_ids, -1)
             best_i, best_d = merge_topk(best_i, best_d, cand_ids, -neg, k)
@@ -393,23 +382,10 @@ def _ring_knn_step_fn(axis: str, k: int, *, same_shard_of: int | None = None):
         (_, _, best_i, best_d), _ = jax.lax.scan(step, init, None, length=size)
         return best_i, best_d
 
-    return ring
-
-
-def make_ring_knn_fn(mesh: Mesh, *, axis: str = "data", k: int = 32):
-    """Exact KNN graph over a row-sharded corpus via a ``ppermute`` ring.
-
-    Each step, every shard scores its rows against the visiting column block
-    and folds the result into its running top-k; the block then moves one hop
-    around the ring.  After ``n_shards`` steps every pair has been scored.
-    This is the sharded replacement for NN-descent bootstrap on corpora that
-    exceed a single host (DESIGN.md §4); the same ring (own-shard-masked)
-    bootstraps the on-device sharded build.
-    """
     row = P((axis,))
-    fn = shard_map(
-        _ring_knn_step_fn(axis, k), mesh=mesh, in_specs=(row, row),
-        out_specs=(row, row), check_vma=False,
+    fn = jax.shard_map(
+        ring, mesh=mesh, in_specs=(row, row), out_specs=(row, row),
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -426,66 +402,17 @@ def _round_robin_layout(n: int, S: int):
     return np.where(gid < n, gid, -1).astype(np.int32), per
 
 
-def build_sharded_store(
-    mesh: Mesh,
-    x: np.ndarray,
-    intervals: np.ndarray,
-    cfg,
-    *,
-    index_axes: Sequence[str] = ("data",),
-    dtype: str = "f32",
-    rerank: bool = False,
-    backend: str | None = None,
-) -> ShardedIndex:
-    """On-device sharded build (DESIGN.md §12): one jitted ``shard_map``
-    program constructs every shard's unified graph in parallel.
-
-    Per shard: the ring-KNN bootstrap (own-shard-masked exact KNN through
-    the ``ppermute`` pipeline — no shard ever holds more than one visiting
-    block) supplies the spatial candidates, shard-local attribute sort
-    orders the Alg. 1 interval candidates, and ``build.refine_candidates``
-    — the *same* jitted ``_prune_all`` + repair-scatter iterations the
-    single-host build runs — refines them into the final graph.  No
-    per-shard host ``build_ug`` calls, no round-robin numpy padding loop:
-    the only host work is the O(n) round-robin permutation and a single
-    device→host sync for the trailing-column trim.
-
-    Rows partition round-robin exactly like the host reference
-    (:func:`build_sharded_index_host`), so the two paths build statistically
-    identical shards (the parity test pins sharded-search recall within
-    0.01 across all four semantics).
-    """
-    if len(index_axes) != 1:
-        raise NotImplementedError(
-            "on-device sharded build rings over one index axis; flatten "
-            "multi-axis meshes into the data axis for construction")
-    axis = index_axes[0]
-    S = mesh.shape[axis]
-    x = np.asarray(x)
-    intervals = np.asarray(intervals)
-    n, d = x.shape
-    gids, per = _round_robin_layout(n, S)
-    n_pad = per * S
-
-    safe = np.clip(gids, 0, n - 1)
-    xs = np.where((gids >= 0)[:, None], x[safe], 0.0).astype(np.float32)
-    its = np.where(
-        (gids >= 0)[:, None], intervals[safe],
-        np.asarray([2.0, -2.0], intervals.dtype),  # pads: no predicate matches
-    )
-
-    row = NamedSharding(mesh, P((axis,)))
-    xs_d = jax.device_put(jnp.asarray(xs), row)
-    its_d = jax.device_put(jnp.asarray(its), row)
-    gids_d = jax.device_put(jnp.asarray(gids), row)
-
-    ring = _ring_knn_step_fn(axis, int(cfg.ef_spatial), same_shard_of=S)
+def shard_build_fn(mesh: Mesh, cfg, *, axis: str = "data",
+                   backend: str | None = None):
+    """The jitted ``shard_map`` program of :func:`build_sharded_store`:
+    ``(x, intervals, global_ids)`` row-sharded over ``axis`` → per-shard
+    ``(nbrs, status)`` at full ``keep`` width."""
 
     def shard_build(xloc, ivloc, gidloc):
         valid = gidloc >= 0
         nloc = xloc.shape[0]
-        # (1) spatial candidates: ring-KNN bootstrap masked to own shard.
-        spa, _ = ring(xloc, gidloc)
+        # (1) spatial candidates: exact KNN over the shard's own rows.
+        spa = brute_force_knn(xloc, int(cfg.ef_spatial), valid=valid).ids
         # (2) attribute candidates: shard-local Alg. 1 sort orders.
         attr = attribute_candidates(ivloc, cfg.ef_attribute)
         cand = jnp.concatenate([spa, attr], axis=1)
@@ -500,27 +427,83 @@ def build_sharded_store(
         return nbrs, stat
 
     rowp = P((axis,))
-    build_fn = jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         shard_build, mesh=mesh, in_specs=(rowp, rowp, rowp),
         out_specs=(rowp, rowp), check_vma=False,
     ))
-    nbrs, stat = build_fn(xs_d, its_d, gids_d)
+
+
+def build_sharded_store(
+    mesh: Mesh,
+    x: np.ndarray,
+    intervals: np.ndarray,
+    cfg,
+    *,
+    index_axes: Sequence[str] = ("data",),
+    dtype: str = "f32",
+    rerank: bool = False,
+    backend: str | None = None,
+) -> ShardedIndex:
+    """On-device sharded build (DESIGN.md §12): one jitted ``shard_map``
+    program constructs every shard's unified graph in parallel.
+
+    Per shard: exact KNN over the shard's own rows (the blocked scan of
+    :func:`~repro.core.candidates.brute_force_knn`, pad rows excluded)
+    supplies the spatial candidates, shard-local attribute sort orders the
+    Alg. 1 interval candidates, and ``build.refine_candidates`` — the
+    *same* jitted ``_prune_all`` + repair-scatter iterations the
+    single-host build runs — refines them into the final graph.  No
+    per-shard host ``build_ug`` calls, no round-robin numpy padding loop:
+    the host computes the O(n) round-robin permutation and places each
+    shard's rows directly on its device (nothing is staged on one device),
+    and makes a single device→host sync for the trailing-column trim.
+
+    Rows partition round-robin exactly like the host reference
+    (:func:`build_sharded_index_host`), so the two paths build statistically
+    identical shards (the parity test pins sharded-search recall within
+    0.01 across all four semantics).
+    """
+    if len(index_axes) != 1:
+        raise NotImplementedError(
+            "on-device sharded build runs over one index axis; flatten "
+            "multi-axis meshes into the data axis for construction")
+    axis = index_axes[0]
+    S = mesh.shape[axis]
+    x = np.asarray(x, np.float32)
+    intervals = np.asarray(intervals)
+    n = x.shape[0]
+    gids, _ = _round_robin_layout(n, S)
+
+    safe = np.clip(gids, 0, n - 1)
+    real = (gids >= 0)[:, None]
+    row = NamedSharding(mesh, P((axis,)))
+    # Host arrays go straight to their row shards: each device receives its
+    # own rows and nothing is staged on one device.
+    xs_d = jax.device_put(np.where(real, x[safe], 0.0).astype(np.float32), row)
+    its_d = jax.device_put(np.where(
+        real, intervals[safe],
+        np.asarray([2.0, -2.0], intervals.dtype),  # pads: no predicate matches
+    ), row)
+    gids_d = jax.device_put(gids, row)
+
+    nbrs, stat = shard_build_fn(mesh, cfg, axis=axis, backend=backend)(
+        xs_d, its_d, gids_d)
 
     # Single device→host sync: global trailing-column trim across shards.
     live_cols = max(int(jnp.max(jnp.sum(nbrs >= 0, axis=1))), 1)
     nbrs = jax.device_put(nbrs[:, :live_cols], row)
     stat = jax.device_put(stat[:, :live_cols], row)
 
-    # Quantization params derive from the real rows (x, not the padded xs —
-    # zero pads would widen the int8 ranges / skew the pq centroids).
+    # Quantization params derive from the real rows only (zero pads would
+    # widen the int8 ranges / skew the pq centroids).
     qparams = None
     if dtype == "int8":
-        qparams = quantization_params(jnp.asarray(x))
+        qparams = quantization_params(xs_d, mask=gids_d >= 0)
     elif dtype == "pq":
-        qparams = train_pq_codebooks(jnp.asarray(x))
+        qparams = train_pq_codebooks(x)
     store = IndexStore(
-        plane=VectorPlane.encode(jnp.asarray(xs), dtype, qparams),
-        rerank=VectorPlane.encode(jnp.asarray(xs), "f32") if rerank else None,
+        plane=VectorPlane.encode(xs_d, dtype, qparams),
+        rerank=VectorPlane.encode(xs_d, "f32") if rerank else None,
         intervals=its_d, nbrs=nbrs, status=stat, entry=None,
     )
     shardings = jax.tree.map(

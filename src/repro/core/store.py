@@ -39,6 +39,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.entry import EntryIndex, build_entry_index
 from repro.core.exact import DenseGraph
@@ -47,15 +48,34 @@ PLANE_TAGS = ("f32", "bf16", "int8", "pq")
 _PLANE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 _QMAX = 127.0  # int8 code range is [-127, 127]; -128 stays unused (symmetric)
 PQ_K = 256     # centroids per subspace — one uint8 code each
-_PQ_TRAIN_SAMPLE = 4096
+# 128 training rows per centroid: with 16 (a 4,096-row sample) the k-means
+# overfits its sample and pq recall falls with corpus size.
+_PQ_TRAIN_SAMPLE = 32768
 _PQ_TRAIN_ITERS = 10
+# Nearest-centroid searches run over row chunks whose (m, rows, 256) f32
+# distance slab stays within this size.  Whole-sample slabs of 1.6 GB and
+# more came back wrong from a TPU v5e: codebooks trained that way had 46%
+# more reconstruction error than the same training on the CPU.
+_PQ_SLAB_BYTES = 64 << 20
+# pq training and encoding matmuls run in full f32.  A TPU's default f32
+# matmul rounds its operands to bf16, which assigns about 8% of the 2-d
+# subvectors of a 96-d row to the wrong centroid; on the CPU this changes
+# nothing.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
-def quantization_params(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-dimension affine (scale, zero) from the corpus column ranges."""
+def quantization_params(
+    x: jnp.ndarray, mask: jnp.ndarray | None = None
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-dimension affine (scale, zero) from the corpus column ranges
+    (over the rows where ``mask`` is set, when given)."""
     x32 = x.astype(jnp.float32)
-    lo = jnp.min(x32, axis=0)
-    hi = jnp.max(x32, axis=0)
+    if mask is None:
+        lo = jnp.min(x32, axis=0)
+        hi = jnp.max(x32, axis=0)
+    else:
+        lo = jnp.min(jnp.where(mask[:, None], x32, jnp.inf), axis=0)
+        hi = jnp.max(jnp.where(mask[:, None], x32, -jnp.inf), axis=0)
     zero = (lo + hi) * 0.5
     scale = jnp.maximum((hi - lo) / (2.0 * _QMAX), 1e-8)
     return scale, zero
@@ -74,9 +94,31 @@ def _pq_sq_dists(xs: jnp.ndarray, cb: jnp.ndarray) -> jnp.ndarray:
     """(m, s, K) squared distances from subvectors to centroids."""
     return (
         jnp.sum(xs * xs, axis=-1)[:, :, None]
-        - 2.0 * jnp.einsum("msd,mkd->msk", xs, cb)
+        - 2.0 * jnp.einsum("msd,mkd->msk", xs, cb, precision=_EXACT)
         + jnp.sum(cb * cb, axis=-1)[:, None, :]
     )
+
+
+def _pq_nearest(xs: jnp.ndarray, cb: jnp.ndarray) -> jnp.ndarray:
+    """``(m, s, dsub)`` subvectors → ``(m, s)`` int32 nearest-centroid ids,
+    in ``lax.map`` steps of at most ``_PQ_SLAB_BYTES`` of distances."""
+    m, s, dsub = xs.shape
+    rows = 1 << max((_PQ_SLAB_BYTES // (m * cb.shape[1] * 4)).bit_length() - 1, 0)
+    if s <= rows:
+        return jnp.argmin(_pq_sq_dists(xs, cb), axis=-1)
+    xp = jnp.pad(xs, ((0, 0), (0, -s % rows), (0, 0)))
+    chunks = xp.reshape(m, -1, rows, dsub).transpose(1, 0, 2, 3)
+    near = jax.lax.map(lambda c: jnp.argmin(_pq_sq_dists(c, cb), axis=-1), chunks)
+    return near.transpose(1, 0, 2).reshape(m, -1)[:, :s]
+
+
+@jax.jit
+def _pq_encode(rows: jnp.ndarray, codebooks: jnp.ndarray) -> jnp.ndarray:
+    """``(b, d)`` rows → ``(b, m)`` uint8 codes (nearest centroid per
+    subspace)."""
+    m, _, dsub = codebooks.shape
+    r = rows.astype(jnp.float32).reshape(rows.shape[0], m, dsub)
+    return _pq_nearest(r.transpose(1, 0, 2), codebooks).T.astype(jnp.uint8)
 
 
 @jax.jit
@@ -86,10 +128,11 @@ def _pq_lloyd(xs: jnp.ndarray, cb: jnp.ndarray) -> jnp.ndarray:
     training deterministic and jit-friendly)."""
 
     def step(cb, _):
-        assign = jnp.argmin(_pq_sq_dists(xs, cb), axis=-1)          # (m, s)
+        assign = _pq_nearest(xs, cb)                                # (m, s)
         onehot = jax.nn.one_hot(assign, cb.shape[1], dtype=jnp.float32)
         counts = jnp.sum(onehot, axis=1)                            # (m, K)
-        sums = jnp.einsum("msk,msd->mkd", onehot, xs)               # (m, K, dsub)
+        sums = jnp.einsum("msk,msd->mkd", onehot, xs,
+                          precision=_EXACT)                         # (m, K, dsub)
         new = sums / jnp.maximum(counts[..., None], 1.0)
         return jnp.where((counts > 0)[..., None], new, cb), None
 
@@ -102,20 +145,23 @@ def train_pq_codebooks(
 ) -> jnp.ndarray:
     """On-device k-means codebook training: ``(m, 256, d/m)`` f32.
 
-    Trains on a deterministic sample of ≤ ``_PQ_TRAIN_SAMPLE`` rows,
+    Trains on a deterministic (seeded) sample of ≤ ``_PQ_TRAIN_SAMPLE`` rows,
     initialized from distinct permuted sample rows per subspace.  The
     result is **frozen** at encode time exactly like the int8 qparams —
     streaming inserts encode new rows under the frozen codebooks
     (retraining would invalidate every stored code)."""
-    x32 = jnp.asarray(x).astype(jnp.float32)
-    n, d = x32.shape
+    n, d = x.shape
     if m is None:
         m = default_pq_m(d)
     if m < 1 or d % m:
         raise ValueError(f"pq subspace count m={m} must divide d={d}")
     s = max(min(n, _PQ_TRAIN_SAMPLE), 1)
-    perm = jax.random.permutation(jax.random.key(seed), max(n, 1))[:s]
-    xs = x32[perm].reshape(s, m, d // m).transpose(1, 0, 2)  # (m, s, dsub)
+    # The sample ids are drawn on the host (a device permutation of a
+    # million ids is a sort that compiles for about a minute on a TPU), and
+    # only the sampled rows move.
+    perm = np.random.default_rng(seed).permutation(max(n, 1))[:s]
+    xs = jnp.asarray(x[perm]).astype(jnp.float32)
+    xs = xs.reshape(s, m, d // m).transpose(1, 0, 2)          # (m, s, dsub)
     init = xs[:, jnp.arange(PQ_K) % s, :]                    # (m, K, dsub)
     return _pq_lloyd(xs, init)
 
@@ -178,10 +224,7 @@ class VectorPlane:
         if self.tag == "bf16":
             return rows.astype(jnp.bfloat16)
         if self.tag == "pq":
-            m, _, dsub = self.codebooks.shape
-            r = rows.astype(jnp.float32).reshape(rows.shape[0], m, dsub)
-            d2 = _pq_sq_dists(r.transpose(1, 0, 2), self.codebooks)  # (m, b, K)
-            return jnp.argmin(d2, axis=-1).T.astype(jnp.uint8)       # (b, m)
+            return _pq_encode(rows, self.codebooks)
         q = jnp.round((rows.astype(jnp.float32) - self.zero) / self.scale)
         return jnp.clip(q, -_QMAX, _QMAX).astype(jnp.int8)
 

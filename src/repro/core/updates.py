@@ -67,8 +67,9 @@ from repro.core.index import UGIndex
 from repro.core.prune import unified_prune
 from repro.core.search import beam_search_flags
 from repro.kernels import ops
+from repro.kernels.beam_merge import next_pow2
 from repro.kernels.expand_score import dedup_first
-from repro.kernels.util import pad_to
+from repro.kernels.util import pad_to, sort_key_i32
 
 # Query window every finite interval satisfies under IF: candidate
 # acquisition searches the IF projection with this window so the fused beam
@@ -184,7 +185,7 @@ def _insert_core(
     attrs = []
     for k_old, k_new in pairs:
         key = jnp.where(alive_old, k_old, jnp.inf)
-        order = jnp.argsort(key)
+        order = jnp.argsort(sort_key_i32(key))
         pos = jnp.searchsorted(key[order], k_new)
         attr_pos = jnp.clip(
             pos[:, None] + offs[None, :], 0, jnp.maximum(n_live - 1, 0)
@@ -378,7 +379,8 @@ def _merge_repair_rows(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m_if", "m_is", "alpha", "unified", "backend", "P", "block"),
+    static_argnames=("m_if", "m_is", "alpha", "unified", "backend", "P", "D",
+                     "block"),
 )
 def _repair_core(
     x, ivs, nbrs, status, del_mask, in_sets, rows,
@@ -389,6 +391,7 @@ def _repair_core(
     unified: bool,
     backend: str | None,
     P: int,
+    D: int,
     block: int,
 ):
     """Repair sweep round 1: re-wire the touched rows through the deleted
@@ -398,13 +401,17 @@ def _repair_core(
     in-neighbor lists of u's deleted neighbors — both sides of the deleted
     node's neighborhood, ids only), deduped with the sort-based
     ``dedup_first``, scored one row at a time by ``ops.expand_score`` (the
-    ``(B, M+2M², d)`` bridge gather is never materialized), truncated to
-    the ``P`` closest, and witness-filtered by the fused Φ sweep.
+    ``(B, M+2MD, d)`` bridge gather is never materialized), truncated to
+    the ``P`` closest, and witness-filtered by the fused Φ sweep.  ``D``
+    is at least the deleted-neighbor count of every touched row, so the
+    pool is ``M + 2·M·D`` wide rather than ``M + 2·M²`` — the same
+    candidates, without the empty slots of live neighbors.
     """
     cap, M = nbrs.shape
     R = rows.shape[0]
     rows_c = jnp.clip(rows, 0, cap - 1)
     row_ok = rows >= 0
+    tiles = ops.plane_tiles(x, backend)     # once, not per map step
 
     def one_block(args):
         u, ok = args                                       # (block,)
@@ -414,19 +421,24 @@ def _repair_core(
         own_ids = jnp.where((own >= 0) & ~own_del, own, -1)
         own_st = jnp.where(own_ids >= 0, status[u], 0)
         # Bridge candidates: out-rows ∪ in-neighbor lists of u's deleted
-        # neighbors (ids only — never gathered as vectors).
+        # neighbors (ids only — never gathered as vectors), in the order
+        # of u's list: the first D deleted positions.
+        first, slot = jax.lax.top_k(
+            jnp.where(own_del, M - jnp.arange(M, dtype=jnp.int32), 0), D)
+        gone = jnp.take_along_axis(own_c, slot, axis=1)          # (block, D)
         bridge = jnp.where(
-            own_del[:, :, None],
-            jnp.concatenate([nbrs[own_c], in_sets[own_c]], axis=-1), -1,
+            (first > 0)[:, :, None],
+            jnp.concatenate([nbrs[gone], in_sets[gone]], axis=-1), -1,
         )
-        bridge = bridge.reshape(u.shape[0], 2 * M * M)
+        bridge = bridge.reshape(u.shape[0], 2 * M * D)
         b_c = jnp.clip(bridge, 0, cap - 1)
         bridge = jnp.where((bridge >= 0) & ~del_mask[b_c], bridge, -1)
-        cand0 = jnp.concatenate([own_ids, bridge], axis=1)  # (block, M+2M²)
+        pad = jnp.full((u.shape[0], max(P - M - 2 * M * D, 0)), -1, jnp.int32)
+        cand0 = jnp.concatenate([own_ids, bridge, pad], axis=1)  # ≥ P wide
         cand0 = jnp.where(cand0 == u[:, None], -1, cand0)
         cand0 = jnp.where(dedup_first(cand0, cand0 >= 0), cand0, -1)
         # Distance-ranked pool truncation through the expand-score kernel.
-        d0 = ops.expand_score(x, cand0, x[u], backend=backend)
+        d0 = ops.expand_score(x, cand0, x[u], backend=backend, tiles=tiles)
         neg, sel = jax.lax.top_k(-d0, P)
         cand = jnp.where(
             jnp.isfinite(neg), jnp.take_along_axis(cand0, sel, axis=1), -1
@@ -512,10 +524,35 @@ def _repair_round(
 
 
 def _pad_rows_1d(idx: np.ndarray, block: int) -> jnp.ndarray:
-    r = pad_to(max(idx.size, 1), block)
+    """Touched-row ids padded with ``-1`` to a bucketed length: a multiple
+    of ``block`` and of a quarter of the largest power of two below the
+    count (at most ~25% padding), so deletes of similar size reuse one
+    compiled repair program instead of compiling one per exact count."""
+    n = max(idx.size, 1)
+    step = max(block, (1 << (n.bit_length() - 1)) // 4)
+    r = pad_to(pad_to(n, step), block)
     out = np.full((r,), -1, np.int32)
     out[: idx.size] = idx
     return jnp.asarray(out)
+
+
+def deleted_in_sets(nbrs, to_del, src_rows) -> jnp.ndarray:
+    """In-neighbor lists of the deleted nodes (the other half of their
+    neighborhood): ``(cap, M)``, row ``v`` holding the rows whose edge
+    list points at deleted ``v``, in scan order.
+
+    ``src_rows`` are the rows holding such an edge (ascending, ``-1``
+    padded).  Scattering only their edges gives the same lists as
+    scattering the whole ``cap × M`` edge list — the rows keep their scan
+    order — and sorts a few thousand pairs instead of ``cap·M``."""
+    cap, M = nbrs.shape
+    r = jnp.clip(src_rows, 0, cap - 1)
+    hit = (src_rows >= 0)[:, None] & to_del[r]
+    return scatter_repairs(
+        jnp.where(hit, nbrs[r], -1).reshape(-1),
+        jnp.where(hit, src_rows[:, None], -1).reshape(-1),
+        cap, M,
+    )
 
 
 def repair_deleted(
@@ -551,24 +588,21 @@ def repair_deleted(
     )
 
     to_del = (nbrs >= 0) & del_mask[jnp.clip(nbrs, 0, cap - 1)]
-    touched = jnp.any(to_del, axis=1) & alive
-    t_idx = np.flatnonzero(np.asarray(touched))            # one host sync
+    row_del = jnp.sum(to_del, axis=1, dtype=jnp.int32)
+    row_del, alive_h = jax.device_get((row_del, alive))     # one host sync
+    n_del = np.where(alive_h, row_del, 0)
+    t_idx = np.flatnonzero(n_del)
     if t_idx.size:
         P = pool if pool is not None else min(4 * M, M + 2 * M * M)
+        # deleted neighbors of the worst touched row, rounded up to a power
+        # of two so similar deletes share one compiled program
+        D = min(next_pow2(int(n_del.max())), M)
         rows = _pad_rows_1d(t_idx, block)
-        # In-neighbor lists of the deleted nodes (the other half of their
-        # neighborhood): one sort/segment-rank scatter over the edge list.
-        src = jnp.broadcast_to(
-            jnp.arange(cap, dtype=jnp.int32)[:, None], nbrs.shape
-        )
-        in_sets = scatter_repairs(
-            jnp.where(to_del, nbrs, -1).reshape(-1),
-            jnp.where(to_del, src, -1).reshape(-1),
-            cap, M,
-        )
+        in_sets = deleted_in_sets(
+            nbrs, to_del, _pad_rows_1d(np.flatnonzero(row_del), block))
         nbrs, status, w_w, w_v = _repair_core(
             x, store.intervals, nbrs, status, del_mask, in_sets, rows,
-            P=P, block=block, **kw,
+            P=P, D=D, block=block, **kw,
         )
         for _ in range(1, repair_iters):
             rep = scatter_repairs(w_w, w_v, cap, cfg.repair_width)
@@ -749,7 +783,7 @@ def update_memory_profile(
     rep = jax.make_jaxpr(
         functools.partial(
             _repair_core, m_if=M, m_is=M, alpha=1.0, unified=True,
-            backend=backend, P=P, block=b,
+            backend=backend, P=P, D=M, block=b,
         )
     )(*repair_args)
 

@@ -14,8 +14,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def ring_all_gather(x: jnp.ndarray, axis_name: str):
     """All-gather along ``axis_name`` as n-1 ppermute hops.
@@ -23,7 +21,7 @@ def ring_all_gather(x: jnp.ndarray, axis_name: str):
     Returns (size, x_full) where x_full has a new leading shard axis in ring
     order starting at the local shard.
     """
-    size = compat.axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % size) for i in range(size)]
 
     def step(carry, _):
@@ -40,7 +38,7 @@ def ring_reduce_scatter(x: jnp.ndarray, axis_name: str):
 
     Each rank ends with the fully-reduced chunk ``x[rank]``.
     """
-    size = compat.axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % size) for i in range(size)]
 
@@ -69,7 +67,7 @@ def ring_streamed_map(
     the next ppermute is in flight (overlap by construction: the permute's
     result is not needed until the next iteration).
     """
-    size = compat.axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % size) for i in range(size)]
 

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_forward(
     mesh: Mesh,
@@ -81,7 +79,7 @@ def pipeline_forward(
         )
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis)),
         out_specs=P(),

@@ -6,12 +6,13 @@ sorted ``ef``-beam every step.  The legacy path re-sorts the whole
 this kernel replaces that with the classic bitonic *partial* merge
 (DESIGN.md §8):
 
-1. bitonic-sort the ``L = W·M`` candidates ascending (``L/2·O(log²L)``
+1. bitonic-sort the ``L = W·M`` candidates (``L/2·O(log²L)``
    compare-exchanges, all vectorized over the lane axis);
-2. keep the best ``E`` candidates, reverse them, and take the elementwise
-   minimum against the (already sorted) beam — the first stage of a bitonic
-   merge of the length-``2E`` concatenation, which provably yields the ``E``
-   smallest elements of the union as a bitonic sequence;
+2. keep the best ``E`` candidates in descending order (the sort runs
+   descending, so no lane reversal is needed) and take the elementwise
+   minimum against the (already sorted) beam — the first stage of a
+   bitonic merge of the length-``2E`` concatenation, which provably yields
+   the ``E`` smallest elements of the union as a bitonic sequence;
 3. one bitonic merge pass (``log E`` stages) re-sorts that sequence.
 
 Amortized over the ``W`` nodes expanded per step this is several times fewer
@@ -44,34 +45,48 @@ def next_pow2(v: int) -> int:
     return p
 
 
-def _cmp_swap(d, p, j: int, asc):
+def _cmp_swap(d, p, j: int, up=None):
     """One compare-exchange stage between lanes ``i`` and ``i ^ j``.
 
-    ``asc`` is a bool (or bool array broadcastable to ``d``) giving the sort
+    ``up`` is ``None`` (every block ascending) or a pair of complementary
+    bool masks ``(asc, desc)`` broadcastable to ``d`` giving the sort
     direction of the block each element belongs to.  Comparison is on the
-    total order ``(d, p)``.
+    total order ``(d, p)``; the network is built from comparisons and
+    and/or only (no bool constants or selects of masks, which Mosaic cannot
+    lower).
     """
     idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, d.ndim - 1)
     is_lo = (idx & j) == 0
+    is_hi = (idx & j) != 0
     pd = jnp.where(is_lo, jnp.roll(d, -j, axis=-1), jnp.roll(d, j, axis=-1))
     pp = jnp.where(is_lo, jnp.roll(p, -j, axis=-1), jnp.roll(p, j, axis=-1))
-    le = (d < pd) | ((d == pd) & (p <= pp))   # self <= partner
-    ge = (d > pd) | ((d == pd) & (p >= pp))   # self >= partner
-    in_order = jnp.where(is_lo, le, ge)       # pair already ascending
-    take_partner = in_order != asc
-    return jnp.where(take_partner, pd, d), jnp.where(take_partner, pp, p)
+    before = (pd < d) | ((pd == d) & (pp < p))   # partner precedes self
+    after = (pd > d) | ((pd == d) & (pp > p))    # partner follows self
+    take_asc = (is_lo & before) | (is_hi & after)
+    if up is None:
+        take = take_asc
+    else:
+        asc, desc = up
+        take = (asc & take_asc) | (desc & ((is_lo & after) | (is_hi & before)))
+    return jnp.where(take, pd, d), jnp.where(take, pp, p)
 
 
-def _bitonic_sort(d, p):
-    """Full ascending bitonic sort along the last axis (power-of-two length)."""
+def _bitonic_sort(d, p, *, descending: bool = False):
+    """Full bitonic sort along the last axis (power-of-two length).
+
+    ``descending`` flips every stage's direction, which mirrors the whole
+    network: the output is the ascending result reversed, bit for bit
+    (``(d, p)`` is a total order)."""
     L = d.shape[-1]
     idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, d.ndim - 1)
     k = 2
     while k <= L:
-        asc = (idx & k) == 0
+        up = ((idx & k) == 0, (idx & k) != 0)
+        if descending:
+            up = up[::-1]
         j = k // 2
         while j >= 1:
-            d, p = _cmp_swap(d, p, j, asc)
+            d, p = _cmp_swap(d, p, j, up)
             j //= 2
         k *= 2
     return d, p
@@ -82,22 +97,24 @@ def _merge_block(beam_d, beam_p, cand_d, cand_p):
     the E smallest of the union, ascending in the ``(d, p)`` total order."""
     E = beam_d.shape[-1]
     L = cand_d.shape[-1]
-    cand_d, cand_p = _bitonic_sort(cand_d, cand_p)
+    # Sorting the candidates *descending* leaves their best E at the tail,
+    # already in the reversed order the bitonic merge stage pairs against
+    # the ascending beam — no lane reversal (which Mosaic cannot lower).
+    rd, rp = _bitonic_sort(cand_d, cand_p, descending=True)
     if L >= E:
-        cand_d = cand_d[..., :E]
-        cand_p = cand_p[..., :E]
+        rd = rd[..., L - E:]
+        rp = rp[..., L - E:]
     else:
-        pad = [(0, 0)] * (cand_d.ndim - 1) + [(0, E - L)]
-        cand_d = jnp.pad(cand_d, pad, constant_values=jnp.inf)
-        cand_p = jnp.pad(cand_p, pad, constant_values=PAD_PAYLOAD)
-    rd = cand_d[..., ::-1]
-    rp = cand_p[..., ::-1]
+        lead = cand_d.shape[:-1] + (E - L,)
+        rd = jnp.concatenate([jnp.full(lead, jnp.inf, rd.dtype), rd], axis=-1)
+        rp = jnp.concatenate(
+            [jnp.full(lead, PAD_PAYLOAD, rp.dtype), rp], axis=-1)
     le = (beam_d < rd) | ((beam_d == rd) & (beam_p <= rp))
     md = jnp.where(le, beam_d, rd)
     mp = jnp.where(le, beam_p, rp)
     j = E // 2
     while j >= 1:
-        md, mp = _cmp_swap(md, mp, j, True)
+        md, mp = _cmp_swap(md, mp, j)
         j //= 2
     return md, mp
 
@@ -170,6 +187,7 @@ def beam_merge(
         ],
         compiler_params=compiler_params(("arbitrary",)),
         interpret=interpret,
+        name="beam_merge",
     )(beam_d, beam_p, cand_d, cand_p)
     return out_d[:B], out_p[:B]
 

@@ -10,13 +10,11 @@ build sweep already eliminated (DESIGN.md §9 → §10).
 
 Three backends, dispatched via :func:`repro.kernels.ops.expand_score`:
 
-* ``pallas`` — scalar-prefetch row gather: the ``(B, C)`` candidate ids are
-  scalar-prefetched, and the corpus BlockSpec's ``index_map`` *reads them*
-  to choose which ``(1, d)`` row to DMA from HBM for each ``(b, c)`` grid
-  step.  The gather happens in the pipeline — each row fetch overlaps the
-  previous step's compute — and the ``(B, C, d)`` tensor never exists.
-  The query row block is reused across the ``C`` inner steps (same block
-  index → no re-fetch).
+* ``pallas`` — tile gather driven by the ids: the ``(B, C)`` candidate ids
+  sit in SMEM, and for each live id the kernel DMAs the HBM tile holding
+  that row into VMEM (double-buffered, one query's chunk of candidates
+  while the previous chunk is scored), picks the row out of the tile and
+  scores it.  The ``(B, C, d)`` tensor never exists.
 * ``xla`` — the interpretable CPU-CI twin: a ``fori_loop`` over
   ``chunk``-wide candidate slices, peak intermediate ``(B, chunk, d)``.
 * ``legacy`` — the pre-fusion baseline (full gather + matmul identity),
@@ -36,8 +34,8 @@ Also here: the sort-based per-row first-occurrence dedup that replaces the
 ``O(C²)`` pairwise mask the search loop used to build twice per step (sort
 by id, mask equal-adjacent, unsort — ``O(C log C)``, no ``(B, C, C)``
 intermediate).  This module absorbs the former ``kernels/gather_dist.py``
-(:func:`gather_sq_dist` is the same scalar-prefetch kernel, kept under its
-historical name for the kernel microbenches).
+(:func:`gather_sq_dist` is the same kernel, kept under its historical name
+for the kernel microbenches).
 """
 from __future__ import annotations
 
@@ -52,58 +50,231 @@ from repro.kernels.util import compiler_params
 
 
 # ------------------------------------------------------------------ pallas
-def _kernel(idx_ref, q_ref, x_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)    # (1, d)
-    x = x_ref[...].astype(jnp.float32)    # (1, d) — the row idx_ref[b, c] chose
-    diff = q - x
-    o_ref[0, 0] = jnp.sum(diff * diff)
+# Shared machinery of the three row-gather kernels (f32/bf16, int8, pq).
+#
+# The grid walks the batch ``_TB`` query rows at a time; each grid step
+# writes a ``(_TB, C)`` output block.  The plane stays in HBM
+# (``memory_space=pl.ANY``) viewed as ``(n/R, R, w)`` tiles — ``R`` rows of
+# one native ``(8, 128)`` 32-bit tile (16 rows for bf16, 32 for 8-bit codes)
+# and ``w`` lanes padded to a multiple of 128 — because Mosaic DMAs whole
+# tiles only: a single-row slice of a tiled HBM array is refused.  For each
+# candidate the kernel DMAs the tile holding its row into a double-buffered
+# VMEM chunk (``K`` candidates per chunk, driven by the ids in SMEM; masked
+# ``-1`` ids issue no DMA), picks the row out of the tile with a masked
+# sublane sum (exact: one value plus zeros), and scores the chunk.  No
+# ``(B, C, d)`` array exists in HBM.
+_TB = 8                  # query rows per grid step (f32 sublane tile)
+_LANE = 128
+_C_ALIGN = 128           # _TB * C must fill whole 1024-word SMEM tiles
+_CHUNK_BYTES = 4 << 20   # one buffer slot of candidate tiles in VMEM
+_VMEM_LIMIT = 48 << 20
+
+
+def _tile_rows(dtype) -> int:
+    """Rows of one native HBM tile: 8 for 32-bit, 16 for 16-bit, 32 for 8-bit."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def tile_view(a: jnp.ndarray) -> jnp.ndarray:
+    """``(n, w)`` plane → ``(ceil(n/R), R, w_pad)`` HBM tile view, ``w_pad`` a
+    multiple of 128.  A pure reshape when ``n % R == 0`` and ``w % 128 ==
+    0``; otherwise one padded copy of the plane — the fused search builds it
+    once per batch (:func:`repro.kernels.ops.plane_tiles`), not per step."""
+    n, w = a.shape
+    R = _tile_rows(a.dtype)
+    n_p = -(-n // R) * R
+    w_p = -(-w // _LANE) * _LANE
+    if (n_p, w_p) != (n, w):
+        a = jnp.pad(a, ((0, n_p - n), (0, w_p - w)))
+    return a.reshape(n_p // R, R, w_p)
+
+
+def _chunk_width(C: int, tile_bytes: int) -> int:
+    """Candidates per DMA chunk: all ``C`` of a query when they fit the
+    budget, else the largest lane-aligned divisor of ``C`` that does."""
+    if C * tile_bytes <= _CHUNK_BYTES or C % _LANE:
+        return C
+    k = _LANE
+    while C % (2 * k) == 0 and 2 * k * tile_bytes <= _CHUNK_BYTES:
+        k *= 2
+    return k
+
+
+def _select_row(tile: jnp.ndarray, s) -> jnp.ndarray:
+    """Row ``s`` of an ``(R, w)`` tile as ``(1, w)``: a masked sublane sum,
+    exact (the row's values plus zeros)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    return jnp.sum(jnp.where(sub == s, tile, jnp.zeros_like(tile)),
+                   axis=0, keepdims=True)
+
+
+def _as_row(col: jnp.ndarray) -> jnp.ndarray:
+    """``(K, 1)`` column → ``(1, K)`` lane row (one XLU transpose)."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], _LANE)))[0:1, :]
+
+
+def _gather_tiles(ids_ref, src, buf, sem, *, tb, C, K, R, consume):
+    """Stream the candidate tiles of ``tb`` query rows through ``buf``.
+
+    ``ids_ref`` holds the ``tb·C`` row ids of this grid step (``-1`` =
+    masked: no DMA).  Chunk ``j = b·(C/K) + p`` (query ``b``, candidates
+    ``[p·K, (p+1)·K)``) lands in slot ``j % 2`` while chunk ``j - 1`` is
+    consumed by ``consume(b, p, slot)``.  ``b`` is static; so is ``p`` when
+    one chunk holds a query's candidates, else ``K`` is a multiple of 128
+    and ``p`` a loop index.
+    """
+    per = C // K
+    n_chunks = tb * per
+
+    def each(j, op):
+        slot = j % 2
+
+        def f(k, carry):
+            r = ids_ref[j * K + k]
+            cp = pltpu.make_async_copy(
+                src.at[jnp.maximum(r, 0) // R], buf.at[slot, k], sem.at[slot])
+            pl.when(r >= 0)(lambda: op(cp))
+            return carry
+
+        jax.lax.fori_loop(0, K, f, 0)
+
+    def chunk(b, p):
+        j = b * per + p
+        pl.when(j + 1 < n_chunks)(lambda: each(j + 1, lambda cp: cp.start()))
+        each(j, lambda cp: cp.wait())
+        consume(b, p, j % 2)
+
+    each(0, lambda cp: cp.start())
+    for b in range(tb):
+        if per == 1:
+            chunk(b, 0)
+            continue
+
+        def body(p, carry, b=b):
+            chunk(b, p)
+            return carry
+
+        jax.lax.fori_loop(0, per, body, 0)
+
+
+def _lanes(p, K: int):
+    """Lane window of chunk ``p`` in the ``(_TB, C)`` output block."""
+    if isinstance(p, int):
+        return slice(p * K, (p + 1) * K)
+    return pl.ds(pl.multiple_of(p * K, _LANE), K)
+
+
+def _flat_ids(idx: jnp.ndarray, n: int, Bp: int, Cp: int) -> jnp.ndarray:
+    """``(B, C)`` ids → flat ``(Bp·Cp,)`` int32, ``-1`` for masked and pad
+    slots, valid ids clipped into the plane."""
+    B, C = idx.shape
+    ids = jnp.where(idx >= 0, jnp.minimum(idx, n - 1), -1).astype(jnp.int32)
+    return jnp.pad(ids, ((0, Bp - B), (0, Cp - C)), constant_values=-1).reshape(-1)
+
+
+def _padded_dims(B: int, C: int) -> tuple[int, int]:
+    return -(-B // _TB) * _TB, -(-C // _C_ALIGN) * _C_ALIGN
+
+
+def _row_gather_call(kernel, ids, row_inputs, tiles, *, Bp, Cp, K, extra_scratch,
+                     name, interpret):
+    """``pallas_call`` over ``Bp/_TB`` query tiles: ``ids`` in SMEM, the
+    per-query ``row_inputs`` (leading axis ``Bp``) and broadcast
+    ``(1, ·)`` params blocked in VMEM, the plane ``tiles`` left in HBM."""
+    def block(a):
+        if a.shape[0] == Bp:
+            return pl.BlockSpec((_TB,) + a.shape[1:],
+                                lambda i, nd=a.ndim: (i,) + (0,) * (nd - 1))
+        return pl.BlockSpec(a.shape, lambda i, nd=a.ndim: (0,) * nd)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(Bp // _TB,),
+        in_specs=[pl.BlockSpec((_TB * Cp,), lambda i: (i,),
+                               memory_space=pltpu.SMEM)]
+        + [block(a) for a in row_inputs]
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((_TB, Cp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Cp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, K) + tiles.shape[1:], tiles.dtype),
+                        *extra_scratch, pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=compiler_params(("arbitrary",),
+                                        vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(ids, *row_inputs, tiles)
+
+
+def _kernel_rows(ids_ref, q_ref, *refs, C, K, R, d, quantized):
+    """Squared L2 of each query row against its gathered candidate rows:
+    ``rows`` collects the ``K`` selected rows of a chunk, then one
+    elementwise square-difference sum over the feature axis scores them —
+    the same network as the XLA twins."""
+    if quantized:
+        s_ref, z_ref, x_hbm, o_ref, buf, rows, sem = refs
+    else:
+        x_hbm, o_ref, buf, rows, sem = refs
+
+    def consume(b, p, slot):
+        def pick(k, carry):
+            t = buf[slot, k]
+            t = t.astype(jnp.int32) if quantized else t.astype(jnp.float32)
+            s = jnp.maximum(ids_ref[b * C + p * K + k], 0) % R
+            rows[pl.ds(k, 1), :] = _select_row(t, s).astype(jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, K, pick, 0)
+        x = rows[:, :d]                                    # (K, d)
+        if quantized:
+            x = x * s_ref[...] + z_ref[...]                # dequant in-register
+        diff = q_ref[b:b + 1, :].astype(jnp.float32) - x
+        dist = jnp.sum(diff * diff, axis=-1, keepdims=True)   # (K, 1)
+        o_ref[b:b + 1, _lanes(p, K)] = _as_row(dist)
+
+    _gather_tiles(ids_ref, x_hbm, buf, sem, tb=_TB, C=C, K=K, R=R,
+                  consume=consume)
+
+
+def _rows_call(x, idx, q, tiles, qparams, interpret):
+    B, C = idx.shape
+    n, d = x.shape
+    if tiles is None:
+        tiles = tile_view(x)
+    Bp, Cp = _padded_dims(B, C)
+    R = tiles.shape[1]
+    K = _chunk_width(Cp, R * tiles.shape[2] * tiles.dtype.itemsize)
+    q = jnp.pad(q, ((0, Bp - B), (0, 0)))
+    kernel = functools.partial(_kernel_rows, C=Cp, K=K, R=R, d=d,
+                               quantized=qparams is not None)
+    out = _row_gather_call(
+        kernel, _flat_ids(idx, n, Bp, Cp), (q,) + tuple(qparams or ()), tiles,
+        Bp=Bp, Cp=Cp, K=K,
+        extra_scratch=[pltpu.VMEM((K, tiles.shape[2]), jnp.float32)],
+        name="expand_score" if qparams is None else "expand_score_q",
+        interpret=interpret,
+    )[:B, :C]
+    return jnp.where(idx >= 0, out, jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def expand_score(
-    x: jnp.ndarray,     # (n, d) corpus (stays in HBM; rows DMA'd on demand)
+    x: jnp.ndarray,     # (n, d) corpus (stays in HBM; tiles DMA'd on demand)
     idx: jnp.ndarray,   # (B, C) int32 candidate ids (-1 = masked/padding)
     q: jnp.ndarray,     # (B, d) queries
     *,
     interpret: bool = False,
+    tiles: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Squared L2 between ``q[b]`` and ``x[idx[b, c]]``; ``+inf`` where
-    ``idx < 0``.  One ``(1, d)`` corpus-row DMA per candidate, scheduled by
-    the scalar-prefetched index array — no ``(B, C, d)`` intermediate."""
-    B, C = idx.shape
-    d = x.shape[1]
-    safe = jnp.clip(idx, 0, x.shape[0] - 1).astype(jnp.int32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, C),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (b, 0)),
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (idx_ref[b, c], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, idx_ref: (b, c)),
-    )
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
-        compiler_params=compiler_params(("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(safe, q, x)
-    return jnp.where(idx >= 0, out, jnp.inf)
+    ``idx < 0``.  One HBM tile DMA per live candidate, scheduled from the
+    ids in SMEM — no ``(B, C, d)`` intermediate.  ``tiles`` is
+    :func:`tile_view` of ``x`` when the caller already built it."""
+    return _rows_call(x, idx, q, tiles, None, interpret)
 
 
 # Historical name from the absorbed kernels/gather_dist.py (microbenches,
 # kernel sweep tests): same kernel, same semantics.
 gather_sq_dist = expand_score
-
-
-# -------------------------------------------------------------- pallas (int8)
-def _kernel_q(idx_ref, q_ref, x_ref, s_ref, z_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)                  # (1, d)
-    xq = x_ref[...].astype(jnp.float32)                 # (1, d) int8 row
-    diff = q - (xq * s_ref[...] + z_ref[...])           # dequant in-register
-    o_ref[0, 0] = jnp.sum(diff * diff)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -115,38 +286,18 @@ def expand_score_q(
     q: jnp.ndarray,      # (B, d) queries
     *,
     interpret: bool = False,
+    tiles: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Quantized-plane :func:`expand_score`: the DMA'd ``(1, d)`` row is int8
-    and dequantized in-register (``x·scale + zero``) before the square-diff
-    sum — the f32 row never exists in HBM, so the per-step row traffic drops
-    4× against the f32 plane.  Same scalar-prefetch schedule, same
+    """Quantized-plane :func:`expand_score`: the gathered rows are int8 codes
+    dequantized in-register (``x·scale + zero``) before the square-diff sum
+    — the f32 row never exists in HBM.  Same tile schedule (an int8 tile
+    holds 32 rows, so a candidate still moves one 4 KiB tile), same
     ``(B, C, d)``-free guarantee, and the same elementwise reduction that
     makes the XLA twin bit-identical under any chunking."""
-    B, C = idx.shape
     d = x.shape[1]
-    safe = jnp.clip(idx, 0, x.shape[0] - 1).astype(jnp.int32)
-    s2 = scale.astype(jnp.float32).reshape(1, d)
-    z2 = zero.astype(jnp.float32).reshape(1, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, C),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (b, 0)),
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (idx_ref[b, c], 0)),
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (0, 0)),
-            pl.BlockSpec((1, d), lambda b, c, idx_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, idx_ref: (b, c)),
-    )
-    out = pl.pallas_call(
-        _kernel_q,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
-        compiler_params=compiler_params(("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(safe, q, x, s2, z2)
-    return jnp.where(idx >= 0, out, jnp.inf)
+    qparams = (scale.astype(jnp.float32).reshape(1, d),
+               zero.astype(jnp.float32).reshape(1, d))
+    return _rows_call(x, idx, q, tiles, qparams, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -206,6 +357,7 @@ def expand_score_q_legacy(
 
 
 # ---------------------------------------------------------------- pallas (pq)
+@jax.jit
 def pq_lut(codebooks: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
     """Per-query subspace distance tables: ``lut[b, j, k]`` is the squared
     L2 between query ``b``'s ``j``-th subvector and centroid ``k`` of
@@ -239,11 +391,32 @@ def _fold_sum_m(vals: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
-def _kernel_pq(idx_ref, lut_ref, codes_ref, o_ref):
-    lut = lut_ref[0]                                    # (m, K) — query b's tables
-    code = codes_ref[0].astype(jnp.int32)               # (m,) — row idx_ref[b, c]
-    vals = jnp.take_along_axis(lut, code[:, None], axis=1)[:, 0]  # (m,)
-    o_ref[0, 0] = _fold_sum_m(vals)
+def _kernel_pq(ids_ref, lut_ref, codes_hbm, o_ref, buf, sem, *, C, K, R, m):
+    """ADC scoring: each gathered code row indexes query ``b``'s transposed
+    ``(256, m)`` tables with a one-hot sublane sum (exact: one entry plus
+    zeros), then :func:`_fold_sum_m` adds the ``m`` lookups in the twin's
+    order."""
+    def consume(b, p, slot):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+
+        def one(k, acc):
+            t = buf[slot, k].astype(jnp.int32)
+            s = jnp.maximum(ids_ref[b * C + p * K + k], 0) % R
+            code = _select_row(t, s)[:, :m]                    # (1, m)
+            lut = lut_ref[b]                                   # (256, m)
+            hit = jax.lax.broadcasted_iota(jnp.int32, lut.shape, 0) == code
+            vals = jnp.sum(jnp.where(hit, lut, jnp.zeros_like(lut)),
+                           axis=0, keepdims=True)              # (1, m)
+            dist = vals[:, 0:1]                     # _fold_sum_m's order
+            for j in range(1, m):
+                dist = dist + vals[:, j:j + 1]
+            return jnp.where(lane == k, dist, acc)
+
+        acc = jax.lax.fori_loop(0, K, one, jnp.zeros((1, K), jnp.float32))
+        o_ref[b:b + 1, _lanes(p, K)] = acc
+
+    _gather_tiles(ids_ref, codes_hbm, buf, sem, tb=_TB, C=C, K=K, R=R,
+                  consume=consume)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -255,38 +428,31 @@ def expand_score_pq(
     *,
     interpret: bool = False,
     lut: jnp.ndarray | None = None,
+    tiles: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """PQ-plane :func:`expand_score`: squared L2 between ``q[b]`` and the
     *decoded* row ``idx[b, c]``, without ever decoding it.  The per-query
     ``(m, 256)`` LUT is built once per batch (:func:`pq_lut`, or passed in
-    precomputed by the fused search loop); each grid step then DMAs one
-    ``(1, m)`` uint8 code row — the same scalar-prefetch schedule as the
-    f32/int8 kernels — and sums ``m`` table lookups in-register.  Per-step
-    row traffic drops from ``4d`` to ``m`` bytes and neither a ``(B, C, d)``
-    gather nor a decoded ``(n, d)`` corpus ever exists."""
+    precomputed by the fused search loop); each candidate then costs one
+    code-tile DMA — the same tile schedule as the f32/int8 kernels — and
+    ``m`` table lookups in-register.  Neither a ``(B, C, d)`` gather nor a
+    decoded ``(n, d)`` corpus ever exists."""
     B, C = idx.shape
     n, m = codes.shape
-    k = codebooks.shape[1]
     if lut is None:
         lut = pq_lut(codebooks, q)
-    safe = jnp.clip(idx, 0, n - 1).astype(jnp.int32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, C),
-        in_specs=[
-            pl.BlockSpec((1, m, k), lambda b, c, idx_ref: (b, 0, 0)),
-            pl.BlockSpec((1, m), lambda b, c, idx_ref: (idx_ref[b, c], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, idx_ref: (b, c)),
-    )
-    out = pl.pallas_call(
-        _kernel_pq,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
-        compiler_params=compiler_params(("arbitrary", "arbitrary")),
+    if tiles is None:
+        tiles = tile_view(codes)
+    Bp, Cp = _padded_dims(B, C)
+    R = tiles.shape[1]
+    K = _chunk_width(Cp, R * tiles.shape[2])
+    lut_t = jnp.pad(jnp.swapaxes(lut, 1, 2), ((0, Bp - B), (0, 0), (0, 0)))
+    kernel = functools.partial(_kernel_pq, C=Cp, K=K, R=R, m=m)
+    out = _row_gather_call(
+        kernel, _flat_ids(idx, n, Bp, Cp), (lut_t,), tiles,
+        Bp=Bp, Cp=Cp, K=K, extra_scratch=[], name="expand_score_pq",
         interpret=interpret,
-    )(safe, lut, codes)
+    )[:B, :C]
     return jnp.where(idx >= 0, out, jnp.inf)
 
 

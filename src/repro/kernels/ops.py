@@ -48,14 +48,16 @@ def filtered_topk(
 
 
 def expand_score(
-    x: jnp.ndarray, idx: jnp.ndarray, q: jnp.ndarray, *, backend: str | None = None
+    x: jnp.ndarray, idx: jnp.ndarray, q: jnp.ndarray, *,
+    backend: str | None = None, tiles: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Beam-expansion scoring: squared L2 between ``q[b]`` and ``x[idx[b,c]]``
     (``+inf`` where ``idx < 0``).
 
-    ``pallas`` scalar-prefetches the id array and DMAs one ``(1, d)`` corpus
-    row per candidate (gather in the pipeline, never materialized); ``xla``
-    is the bit-identical chunked elementwise twin; ``legacy`` the pre-fusion
+    ``pallas`` DMAs the HBM tile of each live candidate row, driven by the
+    ids (gather in the kernel, never materialized; ``tiles`` is the plane's
+    :func:`plane_tiles` view when the caller hoisted it); ``xla`` is the
+    bit-identical chunked elementwise twin; ``legacy`` the pre-fusion
     ``(B, C, d)`` gather + matmul baseline kept for A/B profiling.
     """
     resolved = resolve_backend(backend, choices=("pallas", "xla", "legacy"))
@@ -63,7 +65,20 @@ def expand_score(
         return expand_score_mod.expand_score_legacy(x, idx, q)
     if resolved == "xla":
         return expand_score_mod.expand_score_xla(x, idx, q)
-    return expand_score_mod.expand_score(x, idx, q, interpret=on_cpu())
+    return expand_score_mod.expand_score(
+        x, idx, q, interpret=on_cpu(), tiles=tiles)
+
+
+def plane_tiles(data: jnp.ndarray, backend: str | None = None) -> jnp.ndarray | None:
+    """The HBM tile view the Pallas kernels gather ``data`` (a plane's rows)
+    from; None for the other backends.  Padding rows whose width is not a
+    multiple of 128 lanes copies them, so callers that score the same plane
+    repeatedly (the fused search loop, the delete repair's ``lax.map``)
+    build this once and pass it to every :func:`expand_score_plane` /
+    :func:`expand_score` call, like the pq LUT."""
+    if resolve_backend(backend, choices=("pallas", "xla", "legacy")) != "pallas":
+        return None
+    return expand_score_mod.tile_view(data)
 
 
 def pq_lut(plane, q: jnp.ndarray) -> jnp.ndarray | None:
@@ -79,18 +94,20 @@ def pq_lut(plane, q: jnp.ndarray) -> jnp.ndarray | None:
 def expand_score_plane(
     plane, idx: jnp.ndarray, q: jnp.ndarray, *,
     backend: str | None = None, lut: jnp.ndarray | None = None,
+    tiles: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Beam-expansion scoring against a vector *plane* (core/store.py),
     dispatched on the plane's dtype tag.
 
     ``f32``/``bf16`` route through :func:`expand_score` unchanged (the row
     DMA casts in-register, so bf16 needs no twin); ``int8`` routes through
-    the quantized kernels, which dequantize the ``(1, d)`` row in-register
-    (``x·scale + zero``) — same scalar-prefetch schedule, same traced
-    memory profile, 4× less row traffic.  ``pq`` routes through the
-    LUT-based kernels: a per-query ``(m, 256)`` table built once per batch
-    (pass ``lut`` from :func:`pq_lut` to share it across fused-loop steps),
-    then one ``(1, m)`` uint8 code row DMA'd per candidate.  ``plane`` is
+    the quantized kernels, which dequantize the gathered rows in-register
+    (``x·scale + zero``) — same tile schedule, same traced memory profile,
+    4× fewer plane bytes in HBM.  ``pq`` routes through the LUT-based kernels: a
+    per-query ``(m, 256)`` table built once per batch (pass ``lut`` from
+    :func:`pq_lut` to share it across fused-loop steps), then one uint8
+    code row gathered per candidate.  ``tiles`` is :func:`plane_tiles`
+    hoisted out of the loop the same way.  ``plane`` is
     duck-typed (``tag``/``data``/``scale``/``zero``/``codebooks``) so the
     kernels layer never imports core."""
     if plane.tag == "pq":
@@ -98,13 +115,18 @@ def expand_score_plane(
         if resolved == "legacy":
             return expand_score_mod.expand_score_pq_legacy(
                 plane.data, plane.codebooks, idx, q)
+        if lut is None:
+            # One LUT for either backend: the lookups are exact, so the two
+            # agree bitwise exactly when their tables do.
+            lut = expand_score_mod.pq_lut(plane.codebooks, q)
         if resolved == "xla":
             return expand_score_mod.expand_score_pq_xla(
                 plane.data, plane.codebooks, idx, q, lut=lut)
         return expand_score_mod.expand_score_pq(
-            plane.data, plane.codebooks, idx, q, interpret=on_cpu(), lut=lut)
+            plane.data, plane.codebooks, idx, q, interpret=on_cpu(), lut=lut,
+            tiles=tiles)
     if plane.tag != "int8":
-        return expand_score(plane.data, idx, q, backend=backend)
+        return expand_score(plane.data, idx, q, backend=backend, tiles=tiles)
     resolved = resolve_backend(backend, choices=("pallas", "xla", "legacy"))
     if resolved == "legacy":
         return expand_score_mod.expand_score_q_legacy(
@@ -113,12 +135,13 @@ def expand_score_plane(
         return expand_score_mod.expand_score_q_xla(
             plane.data, plane.scale, plane.zero, idx, q)
     return expand_score_mod.expand_score_q(
-        plane.data, plane.scale, plane.zero, idx, q, interpret=on_cpu())
+        plane.data, plane.scale, plane.zero, idx, q, interpret=on_cpu(),
+        tiles=tiles)
 
 
 def gather_sq_dist(x: jnp.ndarray, idx: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
-    """Beam-expansion scoring via scalar-prefetch row gather (historical
-    name from the absorbed ``kernels/gather_dist.py``)."""
+    """Beam-expansion scoring via the Pallas row gather (historical name
+    from the absorbed ``kernels/gather_dist.py``)."""
     return expand_score_mod.gather_sq_dist(x, idx, q, interpret=on_cpu())
 
 
@@ -130,7 +153,7 @@ def prune_sweep(
     alpha: float = 1.0,
     unified: bool = True,
     backend: str | None = None,
-    bb: int = 32,
+    bb: int | None = None,
 ):
     """Unified interval-aware pruning sweep (Alg. 3) over a node block.
 

@@ -49,32 +49,33 @@ from repro.core import intervals as iv
 from repro.kernels.util import compiler_params, pad_to
 
 
-def cand_row_dist(xs: jnp.ndarray, t) -> jnp.ndarray:
+def cand_row_dist(xs: jnp.ndarray, t, x_t: jnp.ndarray | None = None) -> jnp.ndarray:
     """Distance row ``δ²(c_t, c_w)`` for all ``w``: (B, C, d) → (B, C).
 
     Elementwise square-difference sum (VPU), *not* the matmul identity: the
     per-element reduction over ``d`` is bitwise independent of the batch
     blocking, which the cross-backend bit-identity contract requires.
+    ``x_t`` is candidate ``t``'s ``(B, d)`` vector when the caller already
+    read it (the Pallas kernel slices it from its ref).
     """
-    x_t = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)  # (B, d)
+    if x_t is None:
+        x_t = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)
     diff = xs - x_t[:, None, :]
     return jnp.sum(diff * diff, axis=-1)
 
 
-def _col(a: jnp.ndarray, t) -> jnp.ndarray:
-    """Dynamic column ``a[:, t]`` for a traced scan index ``t``."""
-    return jax.lax.dynamic_index_in_dim(a, t, axis=1, keepdims=False)
-
-
-def _set_col(a: jnp.ndarray, v: jnp.ndarray, t) -> jnp.ndarray:
-    """Write ``a[:, t] = v`` for a traced scan index ``t``."""
-    return jax.lax.dynamic_update_slice_in_dim(a, v[:, None], t, axis=1)
+def _col(a: jnp.ndarray, hit: jnp.ndarray) -> jnp.ndarray:
+    """Column ``a[:, t]`` given ``hit = (col_idx == t)``: a masked lane sum,
+    exact (one value plus zeros) and free of dynamic slices, which Mosaic
+    cannot lower on loaded values."""
+    return jnp.sum(jnp.where(hit, a, jnp.zeros_like(a)), axis=1)
 
 
 def sweep_block(
     i_u: jnp.ndarray,      # (B, 2)  node intervals
     xs: jnp.ndarray,       # (B, C, d) candidate vectors (distance-sorted)
-    i_c: jnp.ndarray,      # (B, C, 2) candidate intervals
+    ic_l: jnp.ndarray,     # (B, C) candidate interval left ends
+    ic_r: jnp.ndarray,     # (B, C) candidate interval right ends
     d_uc: jnp.ndarray,     # (B, C) sorted δ²(u, ·), +inf pads
     valid: jnp.ndarray,    # (B, C) live candidate mask
     overlap: jnp.ndarray,  # (B, C) I_u ∩ I_c ≠ ∅ (all-True when not unified)
@@ -83,70 +84,80 @@ def sweep_block(
     m_is: int,
     alpha: float,
     unified: bool,
+    row_at=None,
 ):
     """The fused Alg. 3 scan over one row block; Φ rows computed per step.
 
     Returns ``(status int32 (B, C), rep_if, rep_is)`` with repair slots
-    *local* to the candidate axis (-1 = kept / invalid).
+    *local* to the candidate axis (-1 = kept / invalid).  Per-step columns
+    are read with masked lane sums and written with selects, and the
+    retained sets are carried as int32 0/1 — one network that both the XLA
+    twin and the Mosaic kernel lower.  ``row_at(t)`` returns candidate
+    ``t``'s ``(B, d)`` vector (default: a dynamic slice of ``xs``).
     """
     B, C = d_uc.shape
     alpha2 = jnp.float32(alpha) ** 2
     col_idx = jax.lax.broadcasted_iota(jnp.int32, (B, C), 1)
+    valid = valid.astype(jnp.int32)
+    overlap = overlap.astype(jnp.int32)
 
     def body(t, state):
         act_if, act_is, cnt_if, cnt_is, rep_if, rep_is = state
-        d_row = cand_row_dist(xs, t)                           # (B, C)
+        hit = col_idx == t
+        x_t = None if row_at is None else row_at(t)
+        d_row = cand_row_dist(xs, t, x_t)                      # (B, C)
         if unified:
-            i_t = jax.lax.dynamic_index_in_dim(i_c, t, axis=1, keepdims=False)  # (B, 2)
-            hull_l = jnp.minimum(i_u[:, 0], i_t[:, 0])
-            hull_r = jnp.maximum(i_u[:, 1], i_t[:, 1])
-            phi_if_row = (hull_l[:, None] <= i_c[..., 0]) & (i_c[..., 1] <= hull_r[:, None])
-            int_l = jnp.maximum(i_u[:, 0], i_t[:, 0])
-            int_r = jnp.minimum(i_u[:, 1], i_t[:, 1])
+            it_l = _col(ic_l, hit)                             # (B,)
+            it_r = _col(ic_r, hit)
+            hull_l = jnp.minimum(i_u[:, 0], it_l)
+            hull_r = jnp.maximum(i_u[:, 1], it_r)
+            phi_if_row = (hull_l[:, None] <= ic_l) & (ic_r <= hull_r[:, None])
+            int_l = jnp.maximum(i_u[:, 0], it_l)
+            int_r = jnp.minimum(i_u[:, 1], it_r)
             nonempty = int_l <= int_r
             phi_is_row = (
                 nonempty[:, None]
-                & (i_c[..., 0] <= int_l[:, None])
-                & (i_c[..., 1] >= int_r[:, None])
+                & (ic_l <= int_l[:, None])
+                & (ic_r >= int_r[:, None])
             )
         else:
-            phi_if_row = jnp.ones((B, C), bool)
-            phi_is_row = jnp.ones((B, C), bool)
+            phi_if_row = col_idx >= 0
+            phi_is_row = col_idx >= 0
 
-        v_ok = _col(valid, t)
-        s_if = v_ok
-        s_is = v_ok & _col(overlap, t)
+        s_if = _col(valid, hit) > 0
+        s_is = s_if & (_col(overlap, hit) > 0)
 
         # Witness scan (Alg. 3 lines 9-17), vectorized over the retained prefix.
-        geo = (col_idx < t) & (alpha2 * d_row < _col(d_uc, t)[:, None])
-        wit_if = geo & act_if & phi_if_row
-        wit_is = geo & act_is & phi_is_row
-        pruned_if = jnp.any(wit_if, axis=1)
-        pruned_is = jnp.any(wit_is, axis=1)
-        j_if = jnp.argmax(wit_if, axis=1).astype(jnp.int32)  # first witness
-        j_is = jnp.argmax(wit_is, axis=1).astype(jnp.int32)
+        geo = (col_idx < t) & (alpha2 * d_row < _col(d_uc, hit)[:, None])
+        wit_if = geo & (act_if > 0) & phi_if_row
+        wit_is = geo & (act_is > 0) & phi_is_row
+        # first witness (C when there is none — only read when pruned)
+        j_if = jnp.min(jnp.where(wit_if, col_idx, C), axis=1)
+        j_is = jnp.min(jnp.where(wit_is, col_idx, C), axis=1)
+        pruned_if = j_if < C
+        pruned_is = j_is < C
 
         keep_if = s_if & ~pruned_if & (cnt_if < m_if)
         keep_is = s_is & ~pruned_is & (cnt_is < m_is)
         cnt_if = cnt_if + keep_if.astype(jnp.int32)
         cnt_is = cnt_is + keep_is.astype(jnp.int32)
 
-        act_if = _set_col(act_if, keep_if, t)
-        act_is = _set_col(act_is, keep_is, t)
-        rep_if = _set_col(rep_if, jnp.where(s_if & pruned_if, j_if, -1), t)
-        rep_is = _set_col(rep_is, jnp.where(s_is & pruned_is, j_is, -1), t)
+        act_if = jnp.where(hit, keep_if.astype(jnp.int32)[:, None], act_if)
+        act_is = jnp.where(hit, keep_is.astype(jnp.int32)[:, None], act_is)
+        rep_if = jnp.where(hit, jnp.where(s_if & pruned_if, j_if, -1)[:, None], rep_if)
+        rep_is = jnp.where(hit, jnp.where(s_is & pruned_is, j_is, -1)[:, None], rep_is)
         return act_if, act_is, cnt_if, cnt_is, rep_if, rep_is
 
     init = (
-        jnp.zeros((B, C), bool),
-        jnp.zeros((B, C), bool),
+        jnp.zeros((B, C), jnp.int32),
+        jnp.zeros((B, C), jnp.int32),
         jnp.zeros((B,), jnp.int32),
         jnp.zeros((B,), jnp.int32),
         jnp.full((B, C), -1, jnp.int32),
         jnp.full((B, C), -1, jnp.int32),
     )
     act_if, act_is, _, _, rep_if, rep_is = jax.lax.fori_loop(0, C, body, init)
-    status = act_if.astype(jnp.int32) * iv.FLAG_IF + act_is.astype(jnp.int32) * iv.FLAG_IS
+    status = act_if * iv.FLAG_IF + act_is * iv.FLAG_IS
     return status, rep_if, rep_is
 
 
@@ -155,12 +166,29 @@ def sweep_block(
 def prune_sweep_xla(i_u, xs, i_c, d_uc, valid, overlap, *, m_if, m_is, alpha, unified):
     """Reference fused backend: the identical network as plain traced jnp."""
     return sweep_block(
-        i_u, xs, i_c, d_uc, valid, overlap,
+        i_u, xs, i_c[..., 0], i_c[..., 1], d_uc, valid, overlap,
         m_if=m_if, m_is=m_is, alpha=alpha, unified=unified,
     )
 
 
 # -------------------------------------------------------------------- pallas
+_XS_BLOCK_BYTES = 4 << 20   # one (bb, C, d) candidate block in VMEM
+_VMEM_LIMIT = 48 << 20
+
+
+def sweep_rows(C: int, d: int) -> int:
+    """Rows per grid cell: the largest of 32, 16, 8 whose ``(bb, C, d)``
+    candidate block (lanes padded to 128) fits ``_XS_BLOCK_BYTES``; 8 when
+    none does.  The block is double-buffered and each scan step makes
+    block-sized temporaries, so a wide pool (the delete repair's 4·M) or a
+    wide row takes fewer rows per cell."""
+    lanes = -(-d // 128) * 128
+    for bb in (32, 16):
+        if bb * C * lanes * 4 <= _XS_BLOCK_BYTES:
+            return bb
+    return 8
+
+
 @functools.partial(
     jax.jit, static_argnames=("m_if", "m_is", "alpha", "unified", "bb", "interpret")
 )
@@ -171,65 +199,60 @@ def prune_sweep(
     m_is: int,
     alpha: float,
     unified: bool,
-    bb: int = 32,
+    bb: int | None = None,
     interpret: bool = False,
 ):
-    """Pallas backend: grid over ``bb``-row tiles, whole sweep in one kernel."""
+    """Pallas backend: grid over ``bb``-row tiles (default
+    :func:`sweep_rows`), whole sweep in one kernel."""
     B, C = d_uc.shape
     d = xs.shape[-1]
+    if bb is None:
+        bb = sweep_rows(C, d)
     Bp = pad_to(B, bb)
+    # The candidate intervals travel as two (B, C) arrays: a (bb, C, 2)
+    # block would pad its last axis to 128 lanes in VMEM.
+    ic_l, ic_r = i_c[..., 0], i_c[..., 1]
+    # Mask operands cross the pallas_call boundary as int32 (Mosaic cannot
+    # take i1 memrefs; every kernel in this repo sticks to f32/i32 operands);
+    # the sweep reads them as int32 0/1 — value-exact.
+    valid = valid.astype(jnp.int32)
+    overlap = overlap.astype(jnp.int32)
     if Bp != B:
         r = Bp - B
         i_u = jnp.pad(i_u, ((0, r), (0, 0)))
         xs = jnp.pad(xs, ((0, r), (0, 0), (0, 0)))
-        i_c = jnp.pad(i_c, ((0, r), (0, 0), (0, 0)))
+        ic_l, ic_r, valid, overlap = (
+            jnp.pad(a, ((0, r), (0, 0))) for a in (ic_l, ic_r, valid, overlap))
         d_uc = jnp.pad(d_uc, ((0, r), (0, 0)), constant_values=jnp.inf)
-        valid = jnp.pad(valid, ((0, r), (0, 0)))
-        overlap = jnp.pad(overlap, ((0, r), (0, 0)))
 
     kernel = functools.partial(
         _kernel, m_if=m_if, m_is=m_is, alpha=alpha, unified=unified
     )
-    # Mask operands cross the pallas_call boundary as int32 (Mosaic cannot
-    # take i1 memrefs; every kernel in this repo sticks to f32/i32 operands)
-    # and are compared back to bool inside the kernel — value-exact.
-    valid = valid.astype(jnp.int32)
-    overlap = overlap.astype(jnp.int32)
-    row2 = lambda i: (i, 0)
-    row3 = lambda i: (i, 0, 0)
+    row2 = pl.BlockSpec((bb, C), lambda i: (i, 0))
     status, rep_if, rep_is = pl.pallas_call(
         kernel,
         grid=(Bp // bb,),
         in_specs=[
-            pl.BlockSpec((bb, 2), row2),
-            pl.BlockSpec((bb, C, d), row3),
-            pl.BlockSpec((bb, C, 2), row3),
-            pl.BlockSpec((bb, C), row2),
-            pl.BlockSpec((bb, C), row2),
-            pl.BlockSpec((bb, C), row2),
-        ],
-        out_specs=[
-            pl.BlockSpec((bb, C), row2),
-            pl.BlockSpec((bb, C), row2),
-            pl.BlockSpec((bb, C), row2),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, C), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, C), jnp.int32),
-        ],
-        compiler_params=compiler_params(("arbitrary",)),
+            pl.BlockSpec((bb, 2), lambda i: (i, 0)),
+            pl.BlockSpec((bb, C, d), lambda i: (i, 0, 0)),
+        ] + [row2] * 5,
+        out_specs=[row2] * 3,
+        out_shape=[jax.ShapeDtypeStruct((Bp, C), jnp.int32)] * 3,
+        compiler_params=compiler_params(("arbitrary",),
+                                        vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(i_u, xs, i_c, d_uc, valid, overlap)
+        name="prune_sweep",
+    )(i_u, xs, ic_l, ic_r, d_uc, valid, overlap)
     return status[:B], rep_if[:B], rep_is[:B]
 
 
-def _kernel(iu_ref, xs_ref, ic_ref, duc_ref, valid_ref, ov_ref,
+def _kernel(iu_ref, xs_ref, icl_ref, icr_ref, duc_ref, valid_ref, ov_ref,
             st_ref, rif_ref, ris_ref, *, m_if, m_is, alpha, unified):
     status, rep_if, rep_is = sweep_block(
-        iu_ref[...], xs_ref[...], ic_ref[...], duc_ref[...],
-        valid_ref[...] != 0, ov_ref[...] != 0,
+        iu_ref[...], xs_ref[...], icl_ref[...], icr_ref[...], duc_ref[...],
+        valid_ref[...], ov_ref[...],
         m_if=m_if, m_is=m_is, alpha=alpha, unified=unified,
+        row_at=lambda t: xs_ref[:, pl.ds(t, 1), :][:, 0, :],
     )
     st_ref[...] = status
     rif_ref[...] = rep_if
@@ -325,24 +348,14 @@ def _iter_eqn_avals(jaxpr):
                 yield from _iter_eqn_avals(sub)
 
 
-def _jaxpr_types():
-    """(ClosedJaxpr, Jaxpr) across jax versions: these classes moved from
-    ``jax.core`` to ``jax.extend.core`` and the old aliases were removed."""
-    try:
-        from jax.extend import core as jcore
-        return jcore.ClosedJaxpr, jcore.Jaxpr
-    except (ImportError, AttributeError):
-        import jax.core as jcore
-        return jcore.ClosedJaxpr, jcore.Jaxpr
-
-
 def _sub_jaxprs(p):
-    closed_t, jaxpr_t = _jaxpr_types()
+    from jax.extend import core as jcore
+
     items = p if isinstance(p, (list, tuple)) else [p]
     for it in items:
-        if isinstance(it, closed_t):
+        if isinstance(it, jcore.ClosedJaxpr):
             yield it.jaxpr
-        elif isinstance(it, jaxpr_t):
+        elif isinstance(it, jcore.Jaxpr):
             yield it
 
 

@@ -1,6 +1,8 @@
 """Shared Pallas kernel utilities (padding, compiler params, backend probe)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -20,6 +22,18 @@ def pad_rows(a: jnp.ndarray, n_pad: int, fill) -> jnp.ndarray:
     )
 
 
+def sort_key_i32(x: jnp.ndarray) -> jnp.ndarray:
+    """int32 keys that sort exactly as the f32 ``x`` does under
+    ``jnp.argsort`` (−0 equals +0; ``x`` holds no NaN): the IEEE bits, with
+    the magnitude bits of negatives flipped.  A TPU compiles an int32 sort
+    of a million keys faster than an f32 one, whose comparator
+    canonicalizes both operands on every compare (PERF.md has the times)."""
+    x = jnp.where(x == 0, jnp.zeros_like(x), x).astype(jnp.float32)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "width"))
 def segment_scatter(
     seg_ids: jnp.ndarray, values: jnp.ndarray, n: int, width: int
 ) -> jnp.ndarray:
@@ -50,18 +64,11 @@ def segment_scatter(
     return out[:n]
 
 
-def compiler_params(dimension_semantics: tuple[str, ...]):
-    """TPU Mosaic compiler params, version-tolerant across jax releases."""
+def compiler_params(dimension_semantics: tuple[str, ...], **kw):
+    """TPU Mosaic compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dimension_semantics)
-            except TypeError:
-                continue
-    return None
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics, **kw)
 
 
 def on_cpu() -> bool:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import Semantics, UGConfig, UGIndex, recall
 from repro.data import CorpusConfig, make_corpus, make_queries
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -52,6 +53,7 @@ def main(argv=None) -> int:
     ap.add_argument("--selftest", action=argparse.BooleanOptionalAction,
                     default=True)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ccfg = CorpusConfig(n=args.n, dim=args.dim, seed=args.seed,
                         interval_mode=args.interval_mode)

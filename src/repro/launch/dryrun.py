@@ -183,8 +183,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, index_cell=False,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         hlo_dir = pathlib.Path("results/hlo")
         hlo_dir.mkdir(parents=True, exist_ok=True)

@@ -9,10 +9,8 @@ import jax
 
 
 def _mk(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)  # older jax: no explicit axis types
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

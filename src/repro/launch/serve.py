@@ -23,6 +23,7 @@ from repro.core import Semantics, UGConfig, UGIndex, recall
 from repro.core import intervals as iv
 from repro.models.api import get_model
 from repro.serve import ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -61,6 +62,7 @@ def main(argv=None) -> int:
                          "deadlines and concurrent churn writes, printing "
                          "sustained QPS and p50/p99 (DESIGN.md §13)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.reduced if args.reduced else spec.config

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from repro.models import shard_ctx
 from repro.models.common import ModelConfig
 
-from repro.compat import shard_map
-
 
 def build_moe_params(cfg: ModelConfig, b, prefix_layers: bool = True):
     L = (cfg.n_layers,) if prefix_layers else ()
@@ -209,7 +207,7 @@ def _moe_ffn_ep(cfg: ModelConfig, p, x: jnp.ndarray):
         specs_in += [P(dp_axes, tp_dim), P(dp_axes, tp_dim), P(tp_dim, dp_axes)]
         args += [p["shared"]["w_gate"], p["shared"]["w_up"], p["shared"]["w_down"]]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=tuple(specs_in),
         out_specs=(row0, P()), check_vma=False,
     )

@@ -116,8 +116,7 @@ def local(gw):
     grads = {"w": gw[0]}
     mean_g, new_ef = compressed_psum(grads, init_ef(grads), "data")
     return mean_g["w"][None]
-from repro.compat import shard_map
-fn = shard_map(local, mesh=mesh2, in_specs=(P("data", None),),
+fn = jax.shard_map(local, mesh=mesh2, in_specs=(P("data", None),),
                out_specs=P("data", None), check_vma=False)
 out = fn(g["w"][:, None, :].reshape(8, 1, 512))
 expect = jnp.mean(g["w"], axis=0)
@@ -134,7 +133,6 @@ def test_ring_collectives():
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed import ring_all_gather, ring_reduce_scatter
 from repro.launch.mesh import make_mesh
 
@@ -144,7 +142,7 @@ x = jax.random.normal(jax.random.key(0), (8, 4))
 def ag(xl):
     size, blocks = ring_all_gather(xl[0], "data")
     return blocks[None]
-out = shard_map(ag, mesh=mesh, in_specs=(P("data", None),),
+out = jax.shard_map(ag, mesh=mesh, in_specs=(P("data", None),),
                 out_specs=P("data", None, None), check_vma=False)(x[:, None, :].reshape(8,1,4))
 # rank r's ring order starts at its own shard going backwards around the ring
 me0 = np.asarray(out[0]).reshape(8, 4)
@@ -154,7 +152,7 @@ assert set(map(tuple, me0.round(4).tolist())) == set(map(tuple, np.asarray(x).ro
 y = jax.random.normal(jax.random.key(1), (8, 8, 4))  # per rank: (8 chunks, 4)
 def rs(yl):
     return ring_reduce_scatter(yl[0], "data")[None]
-out2 = shard_map(rs, mesh=mesh, in_specs=(P("data", None, None),),
+out2 = jax.shard_map(rs, mesh=mesh, in_specs=(P("data", None, None),),
                  out_specs=P("data", None), check_vma=False)(y)
 expect = jnp.sum(y, axis=0)  # sum over ranks, chunk r to rank r
 np.testing.assert_allclose(np.asarray(out2), np.asarray(expect), atol=1e-5)

@@ -25,6 +25,36 @@ def eidx_data():
     return ints, build_entry_index(ints)
 
 
+@pytest.mark.parametrize("n", [1, 1023, 1024, 3001])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_blocked_scan_matches_lax(n, dtype):
+    """The entry index's two-level running min/max equals ``lax.cummin`` /
+    ``lax.cummax`` exactly, both directions, on ragged lengths."""
+    from repro.core.entry import _cumulative
+
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.integers(-1000, 1000, size=n)).astype(dtype)
+    for op, ref in (("min", jax.lax.cummin), ("max", jax.lax.cummax)):
+        for rev in (False, True):
+            np.testing.assert_array_equal(
+                np.asarray(_cumulative(x, op, rev)),
+                np.asarray(ref(x, reverse=rev)))
+
+
+def test_sort_key_i32_sorts_like_f32():
+    """Order-preserving int32 keys give the f32 argsort's permutation,
+    with negative values, ±0, ±inf and ties."""
+    from repro.kernels.util import sort_key_i32
+
+    rng = np.random.default_rng(0)
+    x = np.concatenate([rng.normal(size=2000), np.round(rng.normal(size=500), 1),
+                        [0.0, -0.0, np.inf, -np.inf, -0.0, 0.0]]).astype(np.float32)
+    x = jnp.asarray(x[rng.permutation(x.size)])
+    np.testing.assert_array_equal(
+        np.asarray(jnp.argsort(sort_key_i32(x), stable=True)),
+        np.asarray(jnp.argsort(x, stable=True)))
+
+
 @settings(max_examples=60, deadline=None)
 @given(unit, unit)
 def test_entry_lemma_4_3(ql, qr):

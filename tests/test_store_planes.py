@@ -352,6 +352,20 @@ def test_pq_codebook_training_deterministic():
     np.testing.assert_array_equal(np.asarray(c.data), np.asarray(a.data))
 
 
+def test_pq_nearest_chunks_match_one_slab(monkeypatch):
+    """The nearest-centroid search in row chunks (ragged last chunk
+    padded) returns the ids one whole-sample argmin returns.  Integer
+    coordinates keep every distance exact, so both agree bit for bit."""
+    import repro.core.store as store
+
+    rng = np.random.default_rng(23)
+    xs = jnp.asarray(rng.integers(-8, 9, (3, 1000, 2)), jnp.float32)
+    cb = jnp.asarray(rng.integers(-8, 9, (3, PQ_K, 2)), jnp.float32)
+    whole = np.asarray(jnp.argmin(store._pq_sq_dists(xs, cb), axis=-1))
+    monkeypatch.setattr(store, "_PQ_SLAB_BYTES", 3 * PQ_K * 4 * 96)  # 64 rows
+    np.testing.assert_array_equal(np.asarray(store._pq_nearest(xs, cb)), whole)
+
+
 def test_pq_decode_roundtrip_reasonable():
     x = jax.random.normal(jax.random.key(22), (400, 24))
     plane = VectorPlane.encode(x, "pq")

@@ -187,6 +187,46 @@ def test_delete_then_reinsert_reuses_slot(small_index):
     assert int(hit.ids[0, 0]) == victim
 
 
+@pytest.mark.parametrize("frac", [0.01, 0.2])
+def test_deleted_in_sets_match_full_scatter(frac):
+    """In-neighbor lists scattered from the rows that point at a deleted
+    node equal the scatter over the whole edge list, bit for bit."""
+    from repro.core.build import scatter_repairs
+    from repro.core.updates import _pad_rows_1d, deleted_in_sets
+
+    rng = np.random.default_rng(int(frac * 100))
+    cap, M = 600, 12
+    nbrs = rng.integers(-1, cap, size=(cap, M)).astype(np.int32)
+    dead = rng.uniform(size=cap) < frac
+    to_del = (nbrs >= 0) & dead[np.clip(nbrs, 0, cap - 1)]
+    src = np.broadcast_to(np.arange(cap, dtype=np.int32)[:, None], (cap, M))
+    full = scatter_repairs(jnp.asarray(np.where(to_del, nbrs, -1).reshape(-1)),
+                           jnp.asarray(np.where(to_del, src, -1).reshape(-1)),
+                           cap, M)
+    rows = _pad_rows_1d(np.flatnonzero(to_del.any(axis=1)), 64)
+    got = deleted_in_sets(jnp.asarray(nbrs), jnp.asarray(to_del), rows)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
+
+
+@pytest.mark.parametrize("frac", [0.02, 0.3])
+def test_repair_bridge_bound_is_exact(small_index, monkeypatch, frac):
+    """Bounding the repair bridge by the worst row's deleted-neighbor count
+    (``D``) gives the same graph, bit for bit, as bridging through all
+    ``M`` neighbor slots — light deletes (small ``D``) and heavy ones."""
+    import repro.core.updates as updates
+
+    idx = small_index
+    dels = np.random.default_rng(5).choice(
+        idx.n, size=max(int(frac * idx.n), 1), replace=False).astype(np.int32)
+    bounded = idx.delete(jnp.asarray(dels), repair_iters=2)
+    monkeypatch.setattr(updates, "next_pow2", lambda v: 1 << 30)   # D = M
+    full = idx.delete(jnp.asarray(dels), repair_iters=2)
+    np.testing.assert_array_equal(np.asarray(bounded.store.nbrs),
+                                  np.asarray(full.store.nbrs))
+    np.testing.assert_array_equal(np.asarray(bounded.store.status),
+                                  np.asarray(full.store.status))
+
+
 def test_delete_entire_interval_band(small_index):
     """Deleting every node valid under a window makes the window's IF
     queries NULL-certify (entry -1, all rows -1) — Lemma 4.3 with the
