@@ -43,6 +43,15 @@ run the existing kernels (rows cast in-register), ``int8`` the quantized
 dequant-in-register twins.  When the store carries a rerank plane, the
 final beam is re-scored against the exact f32 vectors before top-k
 extraction, so a quantized scan plane keeps f32-grade answers.
+
+Trace names: :func:`search_mixed` opens the host spans ``search.flags``,
+``search.entry`` and ``search.beam`` (:func:`repro.trace.span`), and each
+part of the fused program sits in a ``jax.named_scope`` that names its ops
+in the op metadata: ``ug.entry`` (seeding the beam from the entry ids),
+``ug.select`` (ExtractMin and the neighbor-list gather), ``ug.visited``
+(bitmap test and set), ``ug.predicate`` (edge status and interval
+predicate), ``ug.dedup``, ``ug.score`` (``expand_score*``), ``ug.merge``
+(``beam_merge``), ``ug.rerank`` and ``ug.topk`` (the final extraction).
 """
 from __future__ import annotations
 
@@ -57,6 +66,7 @@ from repro.core.entry import get_entry_batch_flags, get_entry_flags
 from repro.kernels import ops
 from repro.kernels.beam_merge import PAD_PAYLOAD, next_pow2
 from repro.kernels.expand_score import dedup_first, dedup_first_quadratic
+from repro.trace import span
 
 
 class SearchResult(NamedTuple):
@@ -70,6 +80,12 @@ class SearchResult(NamedTuple):
     # workload benchmark models (DESIGN.md §10) — the same role the
     # comparator count plays for the merge kernel (§8).
     iters: jnp.ndarray | None = None
+
+
+def frontier_width(width: int, ef: int, backend: str | None = None) -> int:
+    """Frontier nodes each loop iteration expands per query: ``W`` of the
+    fused backends, 1 for ``legacy``."""
+    return 1 if backend == "legacy" else max(min(width, ef), 1)
 
 
 def _bitmap_test(bitmap: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
@@ -224,45 +240,52 @@ def _make_fused_step(
     def score(ids_c, valid):
         """Squared distances of the masked candidate ids via the
         expand-score kernel on the store's plane (+inf where invalid)."""
-        return ops.expand_score_plane(
-            plane, jnp.where(valid, ids_c, -1), q32, backend=backend, lut=lut,
-            tiles=tiles,
-        )
-
-    def predicate(obj_int):
-        return iv.predicate_by_flag(sem_flags[:, None], obj_int, q_int[:, None, :])
+        with jax.named_scope("ug.score"):
+            return ops.expand_score_plane(
+                plane, jnp.where(valid, ids_c, -1), q32, backend=backend,
+                lut=lut, tiles=tiles,
+            )
 
     def merge(beam_d, beam_p, cand_d, cand_p):
-        return ops.beam_merge(beam_d, beam_p, cand_d, cand_p, backend=merge_backend)
+        with jax.named_scope("ug.merge"):
+            return ops.beam_merge(beam_d, beam_p, cand_d, cand_p,
+                                  backend=merge_backend)
 
     def step(beam_d, beam_p, visited, steps):
-        # ExtractMin_W: beam is sorted, so top_k picks the W best unexpanded.
-        sel_d = jnp.where((beam_p & 1) == 0, beam_d, jnp.inf)
-        neg, sel_idx = jax.lax.top_k(-sel_d, W)            # (B, W)
-        sel_ok = jnp.isfinite(-neg)
-        u = jnp.take_along_axis(beam_p >> 1, sel_idx, axis=-1)
-        mark = jnp.zeros(beam_p.shape, jnp.int32).at[rowi, sel_idx].max(
-            sel_ok.astype(jnp.int32)
-        )
-        beam_p = beam_p | mark
+        with jax.named_scope("ug.select"):
+            # ExtractMin_W: beam is sorted, so top_k picks the W best
+            # unexpanded.
+            sel_d = jnp.where((beam_p & 1) == 0, beam_d, jnp.inf)
+            neg, sel_idx = jax.lax.top_k(-sel_d, W)        # (B, W)
+            sel_ok = jnp.isfinite(-neg)
+            u = jnp.take_along_axis(beam_p >> 1, sel_idx, axis=-1)
+            mark = jnp.zeros(beam_p.shape, jnp.int32).at[rowi, sel_idx].max(
+                sel_ok.astype(jnp.int32)
+            )
+            beam_p = beam_p | mark
 
-        u_c = jnp.clip(u, 0, n - 1)
-        nb = jnp.where(sel_ok[..., None], nbrs[u_c], -1).reshape(B, C)
-        st = status[u_c].reshape(B, C)
-        present = nb >= 0
-        nb_c = jnp.clip(nb, 0, n - 1)
-        seen = bitmap_test(visited, nb_c) | ~present
+            u_c = jnp.clip(u, 0, n - 1)
+            nb = jnp.where(sel_ok[..., None], nbrs[u_c], -1).reshape(B, C)
+            st = status[u_c].reshape(B, C)
+            present = nb >= 0
+            nb_c = jnp.clip(nb, 0, n - 1)
+        with jax.named_scope("ug.visited"):
+            seen = bitmap_test(visited, nb_c) | ~present
 
-        sem_ok = (st.astype(jnp.int32) & sem_flags[:, None]) > 0
-        pred_ok = predicate(intervals[nb_c])
-        cand_ok = present & ~seen & sem_ok & pred_ok
+        with jax.named_scope("ug.predicate"):
+            sem_ok = (st.astype(jnp.int32) & sem_flags[:, None]) > 0
+            pred_ok = iv.predicate_by_flag(
+                sem_flags[:, None], intervals[nb_c], q_int[:, None, :])
+            cand_ok = present & ~seen & sem_ok & pred_ok
         # Same visited semantics as the legacy path (DESIGN.md §6): mark
         # scored and node-dead candidates, never edge-masked ones.  Across
         # the W lists one id may repeat — score/mark only its first
         # *eligible* occurrence so the scatter-add stays an OR.
-        valid = dedup(nb_c, cand_ok)
-        to_mark = dedup(nb_c, present & ~seen & (cand_ok | ~pred_ok))
-        visited = bitmap_set(visited, nb_c, to_mark)
+        with jax.named_scope("ug.dedup"):
+            valid = dedup(nb_c, cand_ok)
+            to_mark = dedup(nb_c, present & ~seen & (cand_ok | ~pred_ok))
+        with jax.named_scope("ug.visited"):
+            visited = bitmap_set(visited, nb_c, to_mark)
 
         cand_d = score(nb_c, valid)
         cand_p = jnp.where(valid, nb_c << 1, PAD_PAYLOAD)
@@ -309,7 +332,7 @@ def _beam_search_fused(
     """
     n, d = plane.data.shape
     B = q_v.shape[0]
-    W = max(min(width, ef), 1)
+    W = frontier_width(width, ef)
     E = next_pow2(ef)
     nwords = (n + 31) // 32
 
@@ -320,16 +343,17 @@ def _beam_search_fused(
     )
 
     # ---- seed: merge the (deduped) entry batch into an empty beam
-    ent_valid = entry_ids >= 0
-    ent_c = jnp.clip(entry_ids, 0, n - 1)
-    ent_d = score(ent_c, ent_valid)
-    ent_p = jnp.where(ent_valid, ent_c << 1, PAD_PAYLOAD)
-    beam_d = jnp.full((B, E), jnp.inf, jnp.float32)
-    beam_p = jnp.full((B, E), PAD_PAYLOAD, jnp.int32)
-    beam_d, beam_p = merge(beam_d, beam_p, ent_d, ent_p)
-    visited = jax.vmap(_bitmap_set)(
-        jnp.zeros((B, nwords), jnp.uint32), ent_c, ent_valid
-    )
+    with jax.named_scope("ug.entry"):
+        ent_valid = entry_ids >= 0
+        ent_c = jnp.clip(entry_ids, 0, n - 1)
+        ent_d = score(ent_c, ent_valid)
+        ent_p = jnp.where(ent_valid, ent_c << 1, PAD_PAYLOAD)
+        beam_d = jnp.full((B, E), jnp.inf, jnp.float32)
+        beam_p = jnp.full((B, E), PAD_PAYLOAD, jnp.int32)
+        beam_d, beam_p = merge(beam_d, beam_p, ent_d, ent_p)
+        visited = jax.vmap(_bitmap_set)(
+            jnp.zeros((B, nwords), jnp.uint32), ent_c, ent_valid
+        )
 
     iters_cap = (max_steps + W - 1) // W
 
@@ -352,32 +376,30 @@ def _beam_search_fused(
         # extraction always goes through the masked top-k.
         all_ids = beam_p >> 1
         ok = jnp.isfinite(beam_d)
-        beam_d = ops.expand_score(
-            rerank.data, jnp.where(ok, all_ids, -1), q32, backend=backend
-        )
+        with jax.named_scope("ug.rerank"):
+            beam_d = ops.expand_score(
+                rerank.data, jnp.where(ok, all_ids, -1), q32, backend=backend
+            )
         if alive is not None:
             ok = ok & alive[jnp.clip(all_ids, 0, n - 1)]
+    elif alive is None:
+        with jax.named_scope("ug.topk"):
+            dist = beam_d[:, :k]                           # beam is sorted
+            ids = jnp.where(jnp.isfinite(dist), beam_p[:, :k] >> 1, -1)
+        return SearchResult(ids, dist, steps, it)
+    else:
+        # Tombstone extraction: dead beam entries routed during the loop but
+        # must never surface.  The beam is sorted ascending and top_k breaks
+        # ties by position, so with an all-live mask this selects exactly
+        # beam[:, :k] (bit-identical to the static-index path).
+        all_ids = beam_p >> 1
+        ok = jnp.isfinite(beam_d) & alive[jnp.clip(all_ids, 0, n - 1)]
+    with jax.named_scope("ug.topk"):
         neg, sel = jax.lax.top_k(-jnp.where(ok, beam_d, jnp.inf), k)
         dist = -neg
         ids = jnp.where(
             jnp.isfinite(dist), jnp.take_along_axis(all_ids, sel, axis=-1), -1
         )
-        return SearchResult(ids, dist, steps, it)
-    if alive is None:
-        dist = beam_d[:, :k]                               # beam is sorted
-        ids = jnp.where(jnp.isfinite(dist), beam_p[:, :k] >> 1, -1)
-        return SearchResult(ids, dist, steps, it)
-    # Tombstone extraction: dead beam entries routed during the loop but must
-    # never surface.  The beam is sorted ascending and top_k breaks ties by
-    # position, so with an all-live mask this selects exactly beam[:, :k]
-    # (bit-identical to the static-index path).
-    all_ids = beam_p >> 1
-    ok = jnp.isfinite(beam_d) & alive[jnp.clip(all_ids, 0, n - 1)]
-    neg, sel = jax.lax.top_k(-jnp.where(ok, beam_d, jnp.inf), k)
-    dist = -neg
-    ids = jnp.where(
-        jnp.isfinite(dist), jnp.take_along_axis(all_ids, sel, axis=-1), -1
-    )
     return SearchResult(ids, dist, steps, it)
 
 
@@ -523,15 +545,18 @@ def search_mixed(
         raise ValueError(
             "store has no entry structure; build one (make_store/"
             "build_entry_index) or pass entry ids to beam_search_flags")
-    flags = iv.as_sem_flags(sem_flags, q_v.shape[0])
-    if backend == "legacy":
-        entry_ids = get_entry_flags(eidx, q_int, flags)
-    else:
-        entry_ids = get_entry_batch_flags(eidx, q_int, flags, width=width)
-    return beam_search_flags(
-        store, entry_ids, q_v, q_int, flags,
-        ef=ef, k=k, max_steps=max_steps, backend=backend, width=width,
-    )
+    with span("search.flags"):
+        flags = iv.as_sem_flags(sem_flags, q_v.shape[0])
+    with span("search.entry"):
+        if backend == "legacy":
+            entry_ids = get_entry_flags(eidx, q_int, flags)
+        else:
+            entry_ids = get_entry_batch_flags(eidx, q_int, flags, width=width)
+    with span("search.beam"):
+        return beam_search_flags(
+            store, entry_ids, q_v, q_int, flags,
+            ef=ef, k=k, max_steps=max_steps, backend=backend, width=width,
+        )
 
 
 def search(
@@ -586,7 +611,7 @@ def search_step_memory_profile(
     from repro.core.store import VectorPlane, default_pq_m, PQ_K
     from repro.kernels.prune_sweep import _iter_eqn_avals
 
-    C = max(min(width, ef), 1) * M
+    C = frontier_width(width, ef) * M
     E = next_pow2(ef)
     nwords = (n + 31) // 32
     f32, i32 = jnp.float32, jnp.int32
@@ -595,7 +620,7 @@ def search_step_memory_profile(
                  beam_d, beam_p, visited, steps):
         step, _, _ = _make_fused_step(
             plane, intervals, nbrs, status, q_v.astype(f32), q_int, sem_flags,
-            W=max(min(width, ef), 1), backend=backend,
+            W=frontier_width(width, ef), backend=backend,
         )
         return step(beam_d, beam_p, visited, steps)
 

@@ -29,7 +29,16 @@ interleaved IF/IS/RF/RS traffic with concurrent streaming updates:
 * **fleet health** — :class:`FleetServeMonitor` wires the sharded path's
   per-shard probe timings (:func:`~repro.core.sharded.make_shard_probe_fns`)
   into :class:`~repro.ft.straggler.FleetMonitor` slow-shard detection and
-  :func:`~repro.ft.elastic.plan_serve_rescale` replica planning.
+  :func:`~repro.ft.elastic.plan_serve_rescale` replica planning;
+* **spans** — each thread's loop is tiled by per-batch spans
+  (:func:`repro.trace.span`), recorded only while a profiler trace is
+  active: the dispatcher is always inside one of ``serve.dequeue``,
+  ``serve.pack``, ``serve.launch``, ``serve.inflight_put`` or
+  ``serve.write``, the completer inside ``serve.device_wait`` or
+  ``serve.resolve`` while it holds a batch.  Every span of one batch
+  carries its sequence number ``batch``.  No span is opened per request:
+  per-request facts (queue wait, time spent in :meth:`ServeRuntime.submit`)
+  are stamped on the batch's ``serve.pack`` span.
 
 Every row of a fused search batch is bitwise independent of the rest of the
 batch (DESIGN.md §10), which is what makes continuous batching *exact*
@@ -56,9 +65,14 @@ import numpy as np
 from repro.ft.elastic import RescalePlan, plan_serve_rescale
 from repro.ft.straggler import FleetMonitor, StragglerConfig
 from repro.serve.engine import ServeEngine, bucket_batch_size
+from repro.trace import compiles, count_compiles, span
 
 
 _LAT_RESERVOIR_CAP = 4096
+# A span still open when a profiler trace starts is not recorded, so the
+# dispatcher's waits are cut into slices of at most this many seconds, each a
+# span of its own: its loop is then tiled from a trace's first instant on.
+_WAIT_SLICE_S = 0.1
 
 
 class LatencyReservoir:
@@ -144,6 +158,14 @@ class _Query:
 
 
 @dataclasses.dataclass
+class _Batch:
+    seq: int                 # batch sequence number, the spans' ``batch``
+    queries: list[_Query]
+    admit_s: float           # seconds callers spent in submit() since the
+                             # previous batch was dequeued
+
+
+@dataclasses.dataclass
 class _Write:
     kind: str                # "upsert" | "remove"
     payload: tuple
@@ -199,6 +221,12 @@ class ServeRuntime:
         # cycles report qps against the wrong window.
         self._t_start: float | None = None
         self._wall_accum = 0.0
+        self._admit_s = 0.0          # guarded by _cv
+        self._batches = 0            # dequeued query batches (dispatcher)
+        # Sequence numbers of launched batches, in launch order: the
+        # completer resolves batches in that order and pops them.
+        self._launched: collections.deque = collections.deque()
+        count_compiles()
 
     # ------------------------------------------------------------ admission
     def submit(
@@ -221,6 +249,7 @@ class ServeRuntime:
         """
         from repro.core import as_sem_flags
 
+        t_enter = time.perf_counter()
         fut: Future = Future()
         now = self.clock()
         flag = int(np.asarray(as_sem_flags([sem], 1))[0])
@@ -235,7 +264,7 @@ class ServeRuntime:
             int(k if k is not None else self.cfg.default_k),
             deadline, fut, now,
         )
-        self._enqueue(req)
+        self._enqueue(req, t_enter)
         return fut
 
     def submit_upsert(self, x, intervals) -> Future:
@@ -257,8 +286,13 @@ class ServeRuntime:
                              fut, self.clock()))
         return fut
 
-    def _enqueue(self, item) -> None:
+    def _enqueue(self, item, t_enter: float | None = None) -> None:
+        """Append to the FIFO; ``t_enter`` (``perf_counter`` at entry to
+        :meth:`submit`) adds the caller's admission time to the next
+        batch's ``admit_s``."""
         with self._cv:
+            if t_enter is not None:
+                self._admit_s += time.perf_counter() - t_enter
             if self._stopping:
                 raise RuntimeError("runtime is stopping; admission closed")
             if len(self._pending) >= self.cfg.max_queue:
@@ -275,80 +309,114 @@ class ServeRuntime:
     # ----------------------------------------------------------- coalescing
     def _next_work(self, block: bool):
         """Dequeue the next unit of work in FIFO order: either one write op
-        or the longest head run of queries sharing a compile key, capped at
-        ``max_batch``.  Returns None when idle (inline mode) or stopped."""
-        with self._cv:
-            while True:
-                if self._pending:
-                    break
-                if not block or self._stopping:
-                    return None
-                self._cv.wait()
-            head = self._pending[0]
-            if isinstance(head, _Write):
-                self._pending.popleft()
-                return head
-            key = (head.ef, head.k)
-            batch = []
-            while (
-                self._pending
-                and isinstance(self._pending[0], _Query)
-                and (self._pending[0].ef, self._pending[0].k) == key
-                and len(batch) < self.cfg.max_batch
-            ):
-                batch.append(self._pending.popleft())
-            return batch
+        or a :class:`_Batch` of the longest head run of queries sharing a
+        compile key, capped at ``max_batch``.  Returns None when idle
+        (inline mode) or stopped."""
+        while True:
+            with span("serve.dequeue") as sp, self._cv:
+                waited = not self._pending
+                sp.set_metadata(waited=waited)
+                if waited:
+                    if not block or self._stopping:
+                        return None
+                    self._cv.wait(_WAIT_SLICE_S)
+                    if not self._pending:
+                        continue
+                head = self._pending[0]
+                if isinstance(head, _Write):
+                    self._pending.popleft()
+                    return head
+                key = (head.ef, head.k)
+                queries = []
+                while (
+                    self._pending
+                    and isinstance(self._pending[0], _Query)
+                    and (self._pending[0].ef, self._pending[0].k) == key
+                    and len(queries) < self.cfg.max_batch
+                ):
+                    queries.append(self._pending.popleft())
+                batch = _Batch(self._batches, queries, self._admit_s)
+                self._batches += 1
+                self._admit_s = 0.0
+                sp.set_metadata(batch=batch.seq)
+                return batch
 
-    def _launch(self, batch: list[_Query]):
+    def _launch(self, batch: _Batch):
         """Expire dead requests, pin the snapshot, pack + pad the micro-batch
         and *launch* the device program (no blocking here — jax dispatch is
         asynchronous; the completer owns the block)."""
         from repro.core import FLAG_IF
         from repro.core.search import search_mixed
 
-        now = self.clock()
-        live = []
-        for r in batch:
-            if r.deadline is not None and r.deadline <= now:
-                self._reject(r.future, DeadlineExceeded(
-                    f"deadline expired in queue ({now - r.t_submit:.3f}s "
-                    f"after admission)"))
-            else:
-                live.append(r)
-        if not live:
-            return None
-        index = self.engine.index           # pin the snapshot at dequeue time
-        ef, k = live[0].ef, live[0].k
-        B = len(live)
-        qv = jnp.stack([r.q_v for r in live])
-        qint = jnp.stack([r.q_int for r in live])
-        flags = jnp.asarray([r.flag for r in live], jnp.int32)
-        Bp = bucket_batch_size(B)
-        if Bp != B:
-            pad = Bp - B
-            qv = jnp.concatenate([qv, jnp.zeros((pad, qv.shape[1]), qv.dtype)])
-            dead = jnp.broadcast_to(
-                jnp.asarray([2.0, -2.0], qint.dtype), (pad, 2))
-            qint = jnp.concatenate([qint, dead])
-            flags = jnp.concatenate(
-                [flags, jnp.full((pad,), FLAG_IF, jnp.int32)])
-        res = search_mixed(
-            index.store, qv, qint, flags, ef=ef, k=k,
-            backend=self.engine.search_backend, width=self.engine.search_width,
-        )
+        with span("serve.pack", batch=batch.seq) as sp:
+            c0 = compiles()
+            now = self.clock()
+            live = []
+            for r in batch.queries:
+                if r.deadline is not None and r.deadline <= now:
+                    self._reject(r.future, DeadlineExceeded(
+                        f"deadline expired in queue ({now - r.t_submit:.3f}s "
+                        f"after admission)"))
+                else:
+                    live.append(r)
+            if not live:
+                sp.set_metadata(live=0)
+                return None
+            index = self.engine.index       # pin the snapshot at dequeue time
+            ef, k = live[0].ef, live[0].k
+            B = len(live)
+            qv = jnp.stack([r.q_v for r in live])
+            qint = jnp.stack([r.q_int for r in live])
+            flags = jnp.asarray([r.flag for r in live], jnp.int32)
+            Bp = bucket_batch_size(B)
+            if Bp != B:
+                pad = Bp - B
+                qv = jnp.concatenate(
+                    [qv, jnp.zeros((pad, qv.shape[1]), qv.dtype)])
+                dead = jnp.broadcast_to(
+                    jnp.asarray([2.0, -2.0], qint.dtype), (pad, 2))
+                qint = jnp.concatenate([qint, dead])
+                flags = jnp.concatenate(
+                    [flags, jnp.full((pad,), FLAG_IF, jnp.int32)])
+            wait_ms = [1e3 * (now - r.t_submit) for r in live]
+            sp.set_metadata(
+                live=B, bucket=Bp, queue_wait_ms_max=max(wait_ms),
+                queue_wait_ms_med=float(np.median(wait_ms)),
+                admit_s=batch.admit_s, compiles=compiles() - c0)
+        with span("serve.launch", batch=batch.seq) as sp:
+            c0 = compiles()
+            res = search_mixed(
+                index.store, qv, qint, flags, ef=ef, k=k,
+                backend=self.engine.search_backend,
+                width=self.engine.search_width,
+            )
+            sp.set_metadata(compiles=compiles() - c0)
+        self._launched.append(batch.seq)
         return live, res, index
 
     def _complete(self, inflight) -> None:
         """Block on one in-flight micro-batch and resolve its futures."""
+        from repro.core.search import frontier_width
+
         live, res, index = inflight
-        ids = np.asarray(res.ids)           # blocks until the batch is done
-        dist = np.asarray(res.dist)
-        now = self.clock()
-        lats = []
-        for i, r in enumerate(live):
-            lat = now - r.t_submit
-            lats.append(lat)
-            r.future.set_result(ServeReply(ids[i], dist[i], lat, index))
+        seq = self._launched.popleft()
+        with span("serve.device_wait", batch=seq):
+            ids = np.asarray(res.ids)       # blocks until the batch is done
+            dist = np.asarray(res.dist)
+        with span("serve.resolve", batch=seq) as sp:
+            steps = np.asarray(res.steps)[:len(live)]
+            sp.set_metadata(
+                live=len(live), bucket=ids.shape[0],
+                width=frontier_width(self.engine.search_width, live[0].ef,
+                                     self.engine.search_backend),
+                iters=int(res.iters), steps_sum=int(steps.sum()),
+                steps_max=int(steps.max()))
+            now = self.clock()
+            lats = []
+            for i, r in enumerate(live):
+                lat = now - r.t_submit
+                lats.append(lat)
+                r.future.set_result(ServeReply(ids[i], dist[i], lat, index))
         with self._stats_lock:
             self._completed += len(live)
             self._latencies.extend(lats)
@@ -359,12 +427,14 @@ class ServeRuntime:
         atomic reference store, so concurrent dequeues see either the old
         or the new snapshot, never a mix."""
         try:
-            if w.kind == "upsert":
-                x, ivs = w.payload
-                out = self.engine.upsert(None, ivs, x=x)
-            else:
-                ids, repair = w.payload
-                out = self.engine.remove(ids, repair=repair)
+            rows = w.payload[0].shape[0] if w.kind == "upsert" else w.payload[0].size
+            with span("serve.write", kind=w.kind, rows=int(rows)):
+                if w.kind == "upsert":
+                    x, ivs = w.payload
+                    out = self.engine.upsert(None, ivs, x=x)
+                else:
+                    ids, repair = w.payload
+                    out = self.engine.remove(ids, repair=repair)
             with self._stats_lock:
                 self._writes += 1
             w.future.set_result(out)
@@ -403,8 +473,13 @@ class ServeRuntime:
                 self._apply_write(work)
             else:
                 inflight = self._launch(work)
-                if inflight is not None:
-                    self._inflight.put(inflight)   # backpressure at cap
+                while inflight is not None:          # backpressure at cap
+                    with span("serve.inflight_put", batch=work.seq):
+                        try:
+                            self._inflight.put(inflight, timeout=_WAIT_SLICE_S)
+                            inflight = None
+                        except _queue.Full:
+                            pass
         self._inflight.put(None)                   # completer shutdown
 
     def _complete_loop(self) -> None:

@@ -88,6 +88,13 @@ class FakeClock:
 def direct_rows(index, qv, qi, flags, *, ef=64, k=10, sel=None):
     """Reference answers: one padded search_mixed call per selected row set,
     exactly the engine's bucket-padding recipe."""
+    res, B = direct_search(index, qv, qi, flags, ef=ef, k=k, sel=sel)
+    return np.asarray(res.ids)[:B], np.asarray(res.dist)[:B]
+
+
+def direct_search(index, qv, qi, flags, *, ef=64, k=10, sel=None):
+    """The padded ``search_mixed`` result of the selected rows, and how many
+    of its rows are live."""
     idxs = list(range(qv.shape[0])) if sel is None else list(sel)
     B = len(idxs)
     q = jnp.stack([qv[i] for i in idxs])
@@ -100,8 +107,7 @@ def direct_rows(index, qv, qi, flags, *, ef=64, k=10, sel=None):
         w = jnp.concatenate(
             [w, jnp.broadcast_to(jnp.asarray([2.0, -2.0], w.dtype), (pad, 2))])
         f = jnp.concatenate([f, jnp.full((pad,), FLAG_IF, jnp.int32)])
-    res = search_mixed(index.store, q, w, f, ef=ef, k=k)
-    return np.asarray(res.ids)[:B], np.asarray(res.dist)[:B]
+    return search_mixed(index.store, q, w, f, ef=ef, k=k), B
 
 
 # ---------------------------------------------------------------- exactness
@@ -434,3 +440,96 @@ def test_stats_wall_clock_inline_mode():
     # the fake clock does not tick inside the pump, so the active window is
     # ~0 — any qps below completed/1s means idle time leaked in
     assert s["qps"] > 5.0
+
+
+# -------------------------------------------------------------------- spans
+def trace_spans(trace_dir):
+    """The program's ``serve.*``/``search.*`` host spans in the newest trace
+    under ``trace_dir``: ``(name, thread, start_ns, end_ns, stats)``, where
+    ``thread`` tells the trace's lines apart."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith(("serve.", "search.")):
+                    out.append((ev.name, (plane.name, li), ev.start_ns,
+                                ev.end_ns, dict(ev.stats)))
+    return out
+
+
+def test_traced_runtime_spans_each_batch(tmp_path):
+    """Under a profiler trace, each batch carries pack, launch and resolve
+    spans with one ``batch`` number, the search's spans nest inside its
+    launch, the dispatcher's spans tile its loop, and the resolve span's
+    counts equal a direct ``search_mixed`` of the batch's rows."""
+    eng = make_engine()
+    qv, qi, flags = make_queries(20)
+    rt = ServeRuntime(eng, RuntimeConfig(max_batch=8))
+    # Queued before the threads start, so the batches are rows 0-7, 8-15
+    # and 16-19 (padded to 8).
+    futs = [rt.submit(qv[i], qi[i], flags[i]) for i in range(20)]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rt:
+            for f in futs:
+                f.result(timeout=60)
+    finally:
+        jax.profiler.stop_trace()
+    spans = trace_spans(str(tmp_path))
+
+    def of(name):
+        return sorted((s for s in spans if s[0] == name), key=lambda s: s[2])
+
+    packs, launches, resolves = of("serve.pack"), of("serve.launch"), of("serve.resolve")
+    assert [s[4]["batch"] for s in packs] == [0, 1, 2]
+    assert [s[4]["batch"] for s in launches] == [0, 1, 2]
+    assert [s[4]["batch"] for s in resolves] == [0, 1, 2]
+    assert [s[4]["batch"] for s in of("serve.device_wait")] == [0, 1, 2]
+    assert [(s[4]["live"], s[4]["bucket"]) for s in packs] == [(8, 8), (8, 8), (4, 8)]
+    for s in packs:
+        assert s[4]["queue_wait_ms_max"] >= s[4]["queue_wait_ms_med"] >= 0
+        assert s[4]["admit_s"] >= 0 and s[4]["compiles"] >= 0
+
+    # search.* spans nest inside their launch, on the launch's thread.
+    for child in ("search.flags", "search.entry", "search.beam"):
+        kids = of(child)
+        assert len(kids) == 3
+        for kid, launch in zip(kids, launches):
+            assert kid[1] == launch[1]
+            assert launch[2] <= kid[2] <= kid[3] <= launch[3]
+
+    # The dispatcher's top-level spans follow one another without overlap.
+    thread = packs[0][1]
+    top = sorted((s for s in spans if s[1] == thread and s[0].startswith("serve.")),
+                 key=lambda s: s[2])
+    assert {s[0] for s in top} >= {"serve.dequeue", "serve.pack", "serve.launch",
+                                   "serve.inflight_put"}
+    assert all(a[3] <= b[2] for a, b in zip(top, top[1:]))
+
+    for s, rows in zip(resolves, ([*range(8)], [*range(8, 16)], [*range(16, 20)])):
+        res, B = direct_search(eng.index, qv, qi, flags, sel=rows)
+        steps = np.asarray(res.steps)[:B]
+        st = s[4]
+        assert (st["live"], st["bucket"], st["width"]) == (B, res.ids.shape[0], 4)
+        assert st["iters"] == int(res.iters)
+        assert st["steps_sum"] == int(steps.sum())
+        assert st["steps_max"] == int(steps.max())
+        assert 0 < st["steps_sum"] <= st["iters"] * st["width"] * st["bucket"]
+
+
+def test_compile_counter_counts_new_programs():
+    from repro.trace import compiles, count_compiles
+
+    count_compiles()
+    count_compiles()            # registered once: one compile counts once
+    x = jnp.ones((3, 7)).block_until_ready()
+    c0 = compiles()
+    jax.jit(lambda x: x * 3.25 + 1.5)(x).block_until_ready()
+    assert compiles() == c0 + 1
